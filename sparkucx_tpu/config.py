@@ -123,8 +123,7 @@ class TpuShuffleConf:
                              "(io/varlen.py)",
         "compat.version": "host-adapter contract: v1 | v2 "
                           "(compat/__init__.resolve_adapter)",
-        "trace.enabled": "turn on the span tracer (utils/trace.py)",
-        "trace.device": "also record device-time spans",
+        "trace.enabled": "turn on the span tracer's ring (utils/trace.py)",
         "trace.capacity": "tracer ring-buffer size",
         "metrics.reportCapacity": "ExchangeReport ring size per manager "
                                   "(default 64; eviction is tenant-"

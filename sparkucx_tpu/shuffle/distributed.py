@@ -291,6 +291,18 @@ def _local_shards_of(arr: jax.Array, shard_ids: Sequence[int],
     return np.stack([by_start[int(i)] for i in shard_ids])
 
 
+def _pull_local(arr: jax.Array, shard_ids: Sequence[int],
+                rows_per_shard: int, what: str, res=None) -> np.ndarray:
+    """:func:`_local_shards_of` as one ``shuffle.d2h`` span of a read's
+    output (``reader.pull_to_host``: ``rows`` counted against ``res``,
+    ``seg`` not)."""
+    from sparkucx_tpu.shuffle.reader import pull_to_host
+    shard_bytes = arr.nbytes // (arr.shape[0] // rows_per_shard)
+    return pull_to_host(
+        arr, what, res, nbytes=shard_bytes * len(shard_ids),
+        pull=lambda a: _local_shards_of(a, shard_ids, rows_per_shard))
+
+
 class DistributedLazyReaderResult(LazyShuffleReaderResult):
     """Device-resident PARTIAL view for the multi-process device sink:
     the payload stays sharded across every process's devices (zero
@@ -556,25 +568,25 @@ class PendingDistributedShuffle(PendingExchangeBase):
                         # else [S, R] relay counts (hierarchical)
                         ns = 1 if (cur.combine or cur.ordered) \
                             else self._hier_mesh.devices.shape[0]
-                        seg_host = _local_shards_of(seg, self._shard_ids,
-                                                    ns)
+                        seg_host = _pull_local(seg, self._shard_ids, ns,
+                                               "seg")
                     else:
                         # flat uncombined: replicated [P, R] — any
                         # addressable copy is the whole matrix
                         # (np.asarray rejects multi-process arrays)
-                        seg_host = np.asarray(
-                            seg.addressable_shards[0].data)
-                    local_payload = _local_shards_of(
-                        rows_out, self._shard_ids, cap_shard)
+                        from sparkucx_tpu.shuffle.reader import \
+                            pull_to_host
+                        seg_host = pull_to_host(
+                            seg.addressable_shards[0].data, "seg")
                     res = DistributedReaderResult(
-                        R, part_to_shard, self._shard_ids, local_payload,
+                        R, part_to_shard, self._shard_ids, None,
                         seg_host, self._val_shape, self._val_dtype,
                         align_chunk=align_chunk)
                     # the HOST sink force-materializes its local shards
                     # — honest d2h accounting (``read.sink=device`` is
                     # the zero-D2H path above)
-                    from sparkucx_tpu.shuffle.reader import _note_d2h
-                    _note_d2h(res, int(local_payload.nbytes))
+                    res._rows = _pull_local(rows_out, self._shard_ids,
+                                            cap_shard, "rows", res)
                     res.cap_out_used = cur.cap_out
                     if not sharded_seg:
                         # flat plain: the replicated [P, R] seg carries
@@ -734,14 +746,12 @@ class PendingDistributedTieredShuffle(PendingTieredShuffle):
         # host sink: drain ONLY this process's shards — the partial-view
         # contract of the fused distributed path, now per tier
         ns = seg.shape[0] // Pn
-        seg_host = _local_shards_of(seg, self._shard_ids, ns)
-        local_payload = _local_shards_of(rows_out, self._shard_ids,
-                                         cap_shard)
+        seg_host = _pull_local(seg, self._shard_ids, ns, "seg")
         res = DistributedReaderResult(
-            R, part_to_shard, self._shard_ids, local_payload, seg_host,
+            R, part_to_shard, self._shard_ids, None, seg_host,
             self._val_shape, self._val_dtype)
-        from sparkucx_tpu.shuffle.reader import _note_d2h
-        _note_d2h(res, int(local_payload.nbytes))
+        res._rows = _pull_local(rows_out, self._shard_ids, cap_shard,
+                                "rows", res)
         res.cap_out_used = plan.cap_out
         return res
 

@@ -51,10 +51,12 @@ def _build_hier_step(mesh: Mesh, dcn_axis: str, ici_axis: str,
     device ids) — a remeshed-but-identical mesh (PR-7 replay rebinds a
     fresh Mesh object over the same devices) reuses its compiled
     programs instead of recompiling both tiers."""
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                program_name)
     from sparkucx_tpu.shuffle.topology import mesh_cache_key
     from sparkucx_tpu.utils.trace import GLOBAL_TRACER
 
+    name = program_name("exchange_hier", plan)
     # anatomy span (compile phase): on a cache hit this is ~ns; on a
     # miss it wraps the trace+lower of BOTH tiers (the inner
     # compile.step span from stepcache covers the jit alone)
@@ -64,8 +66,9 @@ def _build_hier_step(mesh: Mesh, dcn_axis: str, ici_axis: str,
             ("hier", mesh_cache_key(mesh), dcn_axis, ici_axis, plan, width),
             lambda: _build_hier_step_uncached(mesh, dcn_axis, ici_axis, plan,
                                               width),
-            {"kind": "hier", "cap_in": plan.cap_in, "cap_out": plan.cap_out,
-             "width": width, "impl": plan.impl, "wire": plan.wire})
+            {"kind": "hier", "program": name, "cap_in": plan.cap_in,
+             "cap_out": plan.cap_out, "width": width, "impl": plan.impl,
+             "wire": plan.wire})
 
 
 def _build_hier_step_uncached(mesh: Mesh, dcn_axis: str, ici_axis: str,
@@ -111,10 +114,11 @@ def _build_hier_step_uncached(mesh: Mesh, dcn_axis: str, ici_axis: str,
         rows_out, seg, total, ovf2 = stage2(relay, nv2)
         return rows_out, seg, total, ovf1 | ovf2
 
+    from sparkucx_tpu.shuffle.stepcache import jit_named, program_name
     spec = P((dcn_axis, ici_axis))
     sm = jax.shard_map(step, mesh=mesh, in_specs=(spec, spec),
                        out_specs=(spec,) * 4)
-    return jax.jit(sm)
+    return jit_named(sm, program_name("exchange_hier", plan))
 
 
 def submit_shuffle_hierarchical(

@@ -1129,7 +1129,7 @@ class TpuShuffleManager:
         rows are not a prefix) cannot ride the prefix-based check and
         keeps the staged-only posture, warn-once."""
         from sparkucx_tpu.shuffle import integrity as integ
-        from sparkucx_tpu.shuffle.reader import _note_d2h
+        from sparkucx_tpu.shuffle.reader import pull_to_host
         views = res.wave_views()
         if not views:
             return
@@ -1151,8 +1151,8 @@ class TpuShuffleManager:
         if rows_dev is None or totals_dev is None:
             return        # already drained/consumed: nothing to sample
         # key lanes only: the check reads cols 0..1 of the valid prefix
-        rows = np.asarray(rows_dev[:, :2])   # COPY — buffers stay live
-        _note_d2h(v, rows.nbytes)
+        # (a COPY — the buffers stay live)
+        rows = pull_to_host(rows_dev[:, :2], "rows", v)
         totals = np.asarray(totals_dev).reshape(-1)
         try:
             verified = integ.verify_key_routing(
@@ -2147,10 +2147,14 @@ class TpuShuffleManager:
                     f"complete but maps {missing[:8]} have no committed "
                     f"staged rows in this manager — map output lost "
                     f"(writer replaced or released?)")
-            shard_outputs, has_vals, val_tail, val_dtype = \
-                self._materialize_outputs(
-                    writers, Pn, lambda ordinal, map_id: map_id % Pn,
-                    entry=handle.entry, rep=rep)
+            # anatomy span (pack phase): every committed map output
+            # gathered into its shard's list
+            with tracer.span("shuffle.stage", maps=len(writers),
+                             trace=rep.trace_id):
+                shard_outputs, has_vals, val_tail, val_dtype = \
+                    self._materialize_outputs(
+                        writers, Pn, lambda ordinal, map_id: map_id % Pn,
+                        entry=handle.entry, rep=rep)
 
             # int32-range guard on what actually feeds the plan arithmetic:
             # the per-DEVICE aggregated transfer matrix, not the raw [M, R]
@@ -3558,10 +3562,12 @@ class TpuShuffleManager:
         from sparkucx_tpu.shuffle.distributed import (
             allgather_blob, allgather_sizes, submit_shuffle_distributed)
 
-        shard_outputs, has_vals, val_tail, val_dtype = \
-            self._materialize_outputs(
-                writers, L, lambda ordinal, map_id: ordinal % L,
-                entry=handle.entry, rep=rep)
+        with tracer.span("shuffle.stage", maps=len(writers),
+                         trace=rep.trace_id):
+            shard_outputs, has_vals, val_tail, val_dtype = \
+                self._materialize_outputs(
+                    writers, L, lambda ordinal, map_id: ordinal % L,
+                    entry=handle.entry, rep=rep)
         local_rows_n = sum(k.shape[0]
                            for outs in shard_outputs for k, _ in outs)
 

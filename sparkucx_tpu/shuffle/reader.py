@@ -48,29 +48,40 @@ from sparkucx_tpu.shuffle.plan import (ShufflePlan, plan_takes_seed,
                                        wire_row_words)
 from sparkucx_tpu.utils.logging import get_logger
 from sparkucx_tpu.utils.metrics import C_D2H, GLOBAL_METRICS
+from sparkucx_tpu.utils.trace import GLOBAL_TRACER
 
 log = get_logger("shuffle.reader")
 
 KEY_WORDS = 2  # int64 key as two int32 columns [lo, hi]
 
 
-def _note_d2h(res, nbytes: int) -> None:
-    """Account one device-to-host payload pull by a reader result: the
+def pull_to_host(arr, what: str, res=None, nbytes: Optional[int] = None,
+                 pull=np.asarray) -> np.ndarray:
+    """``pull(arr)`` — one device-to-host copy of a read's output — as a
+    ``shuffle.d2h`` span with attrs ``bytes`` and ``what``: ``rows`` for
+    payload, ``seg`` for the delivered-count matrix. ``nbytes`` defaults
+    to ``arr.nbytes``; a pull that copies only part of ``arr`` (this
+    process's shards of a global array) passes the bytes it copies.
+
+    Payload pulls are accounted to the reader result ``res``: the
     cumulative ``shuffle.read.d2h.bytes`` counter (the figure the device
     sink drives to ZERO — bench --stage devread gates it) plus the
     owning read's ExchangeReport when the manager armed the callback
     (``_d2h_cb``, set at on_done). Pulls that happen BEFORE arming (the
     distributed force-materialize runs inside result()) park in
-    ``_d2h_early`` for the manager to flush. Payload only — tiny seg
-    matrices are metadata and deliberately excluded."""
-    if nbytes <= 0:
-        return
-    GLOBAL_METRICS.inc(C_D2H, float(nbytes))
-    cb = getattr(res, "_d2h_cb", None)
-    if cb is not None:
-        cb(int(nbytes))
-    else:
-        res._d2h_early = getattr(res, "_d2h_early", 0) + int(nbytes)
+    ``_d2h_early`` for the manager to flush. Seg matrices are metadata
+    and deliberately not counted."""
+    n = int(arr.nbytes if nbytes is None else nbytes)
+    with GLOBAL_TRACER.span("shuffle.d2h", bytes=n, what=what):
+        host = pull(arr)
+    if what == "rows" and n > 0:
+        GLOBAL_METRICS.inc(C_D2H, float(n))
+        cb = getattr(res, "_d2h_cb", None)
+        if cb is not None:
+            cb(n)
+        else:
+            res._d2h_early = getattr(res, "_d2h_early", 0) + n
+    return host
 
 
 @functools.lru_cache(maxsize=32)
@@ -493,19 +504,23 @@ def _build_step(mesh: Mesh, axis: str, plan: ShufflePlan, width: int):
     observable (compile.step.* counters) and shared with the hierarchical
     builder and manager.warmup. The pipeline itself is
     :func:`step_body`."""
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                program_name)
     # the plan rides the key whole, so the wire tier (plan.wire — part
     # of plan.family too) names its own compiled program per shape
     # family: raw and int8 runs of one shape never collide on a step
+    name = program_name("exchange", plan)
     return GLOBAL_STEP_CACHE.get(
         ("flat", mesh, axis, plan, width),
         lambda: _build_step_uncached(mesh, axis, plan, width),
-        {"kind": "flat", "cap_in": plan.cap_in, "cap_out": plan.cap_out,
-         "width": width, "impl": plan.impl, "wire": plan.wire})
+        {"kind": "flat", "program": name, "cap_in": plan.cap_in,
+         "cap_out": plan.cap_out, "width": width, "impl": plan.impl,
+         "wire": plan.wire})
 
 
 def _build_step_uncached(mesh: Mesh, axis: str, plan: ShufflePlan,
                          width: int):
+    from sparkucx_tpu.shuffle.stepcache import jit_named, program_name
     step = step_body(plan, axis)
     seg_spec = P(axis) if (plan.combine or plan.ordered) else P()
 
@@ -514,7 +529,7 @@ def _build_step_uncached(mesh: Mesh, axis: str, plan: ShufflePlan,
     sm = jax.shard_map(step, mesh=mesh, in_specs=(P(axis), P(axis)),
                        out_specs=(P(axis), seg_spec, P(axis), P(axis)),
                        check_vma=False)
-    return jax.jit(sm)
+    return jit_named(sm, program_name("exchange", plan))
 
 
 def pack_rows(keys: np.ndarray, values: Optional[np.ndarray],
@@ -765,7 +780,6 @@ class ShuffleReaderResult:
         for. For the lazy subclass the first fetch of a shard carries its
         D2H wait, later fetches are host slicing — exactly the
         block-arrival distribution the reference measures."""
-        from sparkucx_tpu.utils.trace import GLOBAL_TRACER
         with GLOBAL_TRACER.span("shuffle.fetch", partition=r) as sp:
             shard = int(self._part_to_shard[r])
             block = self._partition_block(r, shard)
@@ -867,14 +881,14 @@ class LazyShuffleReaderResult(ShuffleReaderResult):
         with self._fetch_lock:
             if self._seg is None:
                 if self._per_shard_segs:
-                    self._seg = np.asarray(self._seg_dev).reshape(
+                    self._seg = pull_to_host(self._seg_dev, "seg").reshape(
                         self._num_shards, -1, self.num_partitions)
                 else:
                     # replicated output: any addressable copy is the
                     # whole matrix (np.asarray would reject a
                     # multi-process array)
-                    self._seg = np.asarray(
-                        self._seg_dev.addressable_shards[0].data)
+                    self._seg = pull_to_host(
+                        self._seg_dev.addressable_shards[0].data, "seg")
                 self._seg_dev = None
         return super()._seg_matrix(shard)
 
@@ -904,8 +918,7 @@ class LazyShuffleReaderResult(ShuffleReaderResult):
                 dev = self._shard_dev(shard)
                 if dev is None:
                     raise KeyError(f"shard {shard} not addressable here")
-                got = np.asarray(dev)
-                _note_d2h(self, got.nbytes)
+                got = pull_to_host(dev, "rows", self)
                 self._shards[shard] = got
                 if len(self._shards) == self._num_shards:
                     # every shard is host-side; drop the device buffers
@@ -1090,8 +1103,7 @@ class LazyShuffleReaderResult(ShuffleReaderResult):
                 start = min(s, cap - bucket)
                 sl = _jax.lax.dynamic_slice_in_dim(dev, start, bucket,
                                                    axis=0)
-                host = np.asarray(sl)
-                _note_d2h(self, host.nbytes)
+                host = pull_to_host(sl, "rows", self)
                 blocks.append(host[s - start:s - start + n])
             block = blocks[0] if len(blocks) == 1 \
                 else np.concatenate(blocks)
@@ -1223,20 +1235,23 @@ def _build_merge_step(mesh: Mesh, axis: str, plan: ShufflePlan,
     fold is its last consumer, so XLA may alias the output into its
     HBM; the wave buffer frees through consume()'s dropped references."""
     from sparkucx_tpu.shuffle.plan import merge_family
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                program_name)
     fam = merge_family(plan, acc_cap, wave_cap, width, merge_impl)
+    name = program_name("wave_merge", plan)
     return GLOBAL_STEP_CACHE.get(
         ("devmerge", mesh, axis) + fam,
         lambda: _build_merge_step_uncached(mesh, axis, plan, acc_cap,
                                            wave_cap, width, merge_impl),
-        {"kind": "devmerge", "acc_cap": acc_cap, "wave_cap": wave_cap,
-         "width": width, "impl": merge_impl,
+        {"kind": "devmerge", "program": name, "acc_cap": acc_cap,
+         "wave_cap": wave_cap, "width": width, "impl": merge_impl,
          "mode": "combine" if plan.combine else "ordered"})
 
 
 def _build_merge_step_uncached(mesh: Mesh, axis: str, plan: ShufflePlan,
                                acc_cap: int, wave_cap: int, width: int,
                                merge_impl: str):
+    from sparkucx_tpu.shuffle.stepcache import jit_named, program_name
     step = merge_step_body(plan, acc_cap, wave_cap, merge_impl)
     sm = jax.shard_map(step, mesh=mesh, in_specs=(P(axis),) * 4,
                        out_specs=(P(axis), P(axis), P(axis)),
@@ -1246,7 +1261,8 @@ def _build_merge_step_uncached(mesh: Mesh, axis: str, plan: ShufflePlan,
     # and XLA flags the differently-shaped wave operand as an unusable
     # donation (a per-call warning) when it cannot alias it into the
     # acc-shaped output
-    return jax.jit(sm, donate_argnums=(0,))
+    return jit_named(sm, program_name("wave_merge", plan),
+                     donate_argnums=(0,))
 
 
 def _build_seed_acc(mesh: Mesh, axis: str, acc_cap: int, wave_cap: int,
@@ -1258,7 +1274,9 @@ def _build_seed_acc(mesh: Mesh, axis: str, acc_cap: int, wave_cap: int,
     pad/truncate to ``acc_cap`` (valid rows are a prefix and fit by the
     acc sizing), not a sort. Saves one full merge program per read —
     on dispatch-bound backends that is a whole launch."""
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                jit_named, program_name)
+    name = program_name("wave_seed")
 
     def build():
         def seed(rows, seg, nv):
@@ -1276,12 +1294,12 @@ def _build_seed_acc(mesh: Mesh, axis: str, acc_cap: int, wave_cap: int,
         # shaped input when the caps differ, and XLA warns per call on
         # an unusable donation; the wave buffer frees through the
         # dropped references either way
-        return jax.jit(sm)
+        return jit_named(sm, name)
 
     return GLOBAL_STEP_CACHE.get(
         ("devmerge-seed", mesh, axis, acc_cap, wave_cap, width,
          num_parts), build,
-        {"kind": "devmerge-seed", "acc_cap": acc_cap,
+        {"kind": "devmerge-seed", "program": name, "acc_cap": acc_cap,
          "wave_cap": wave_cap, "width": width})
 
 
@@ -1318,7 +1336,7 @@ def device_merge_fold(res: "DeviceShuffleReaderResult", mesh: Mesh,
 
     The accumulator capacity derives from the REAL per-shard delivered
     totals across waves (one [P]-int pull per wave — metadata-class,
-    the seg-matrix exclusion precedent of ``_note_d2h``), quantized on
+    the seg-matrix exclusion precedent of ``pull_to_host``), quantized on
     the cap-bucket ladder so same-shaped warm reads land on the same
     compiled merge program (0 warm recompiles). The blocked pallas merge
     keeps both runs VMEM-resident, so a fold larger than
@@ -1371,7 +1389,6 @@ def device_merge_fold(res: "DeviceShuffleReaderResult", mesh: Mesh,
         seg_box["seg"] = pcounts
         return (out_rows, out_n)
 
-    from sparkucx_tpu.utils.trace import GLOBAL_TRACER
     with GLOBAL_TRACER.span("shuffle.merge", waves=len(views),
                             impl=merge_impl):
         acc_rows, acc_n = res.consume(fold, None)
@@ -1472,13 +1489,16 @@ class WavedShuffleReaderResult(ShuffleReaderResult):
                               for w in self._waves) if b.shape[0]]
         if not blocks:
             return self._waves[0]._partition_block(r, shard)
-        if self._plan.combine and len(blocks) > 1:
-            block = combine_packed_rows(
-                blocks, self._plan.combine_words,
-                np.dtype(self._plan.combine_dtype),
-                self._plan.combine_sum_words)
-        elif self._plan.ordered and len(blocks) > 1:
-            block = merge_sorted_rows(blocks)
+        if (self._plan.combine or self._plan.ordered) and len(blocks) > 1:
+            with GLOBAL_TRACER.span("shuffle.merge", waves=len(blocks),
+                                    impl="host"):
+                if self._plan.combine:
+                    block = combine_packed_rows(
+                        blocks, self._plan.combine_words,
+                        np.dtype(self._plan.combine_dtype),
+                        self._plan.combine_sum_words)
+                else:
+                    block = merge_sorted_rows(blocks)
         elif len(blocks) == 1:
             block = blocks[0]
         else:
@@ -1790,7 +1810,6 @@ class PendingExchangeBase:
                 # anatomy span (pack phase): the deferred first dispatch
                 # runs here, outside the manager's dispatch span — the
                 # admission wait above is covered, this must be too
-                from sparkucx_tpu.utils.trace import GLOBAL_TRACER
                 with GLOBAL_TRACER.span("shuffle.dispatch",
                                         deferred=True):
                     self._dispatch()
@@ -1875,7 +1894,6 @@ class PendingShuffle(PendingExchangeBase):
         self._out = step(rows_flat, nvalid)
 
     def _result_inner(self) -> ShuffleReaderResult:
-        from sparkucx_tpu.utils.trace import GLOBAL_TRACER
         while True:
             rows_out, seg, total, ovf = self._out
             # anatomy span: materializing the overflow flag blocks until
@@ -1959,7 +1977,7 @@ class PendingShuffle(PendingExchangeBase):
                 # partition() anyway.
                 res.recv_rows_needed = max_recv_rows(
                     res._seg_matrix(0) if not self._per_shard_segs
-                    else np.asarray(seg).reshape(Pn, -1, R),
+                    else pull_to_host(seg, "seg").reshape(Pn, -1, R),
                     np.asarray(_blocked_map(R, Pn)), Pn)
             return res
 

@@ -135,6 +135,26 @@ def harvest_cost_record(fn, args, kwargs) -> dict:
     return rec
 
 
+def program_name(kind: str, plan=None) -> str:
+    """The name a read-path program compiles under: what it does and,
+    given the plan, the read mode (``shuffle_exchange_ordered``). The
+    profiler's ``XLA Modules`` line shows it as ``jit_<name>``, and the
+    program's ``compile.step`` span carries it as ``program``."""
+    if plan is None:
+        return f"shuffle_{kind}"
+    mode = ("combine" if plan.combine
+            else "ordered" if plan.ordered else "plain")
+    return f"shuffle_{kind}_{mode}"
+
+
+def jit_named(fn: Callable, name: str, **jit_kwargs) -> Callable:
+    """``jax.jit(fn)`` compiled under ``name`` (jit names its program
+    after the function, which for a shard_map is the inner body's)."""
+    import jax
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
 class _TimedStep:
     """Callable proxy over a jitted step: the FIRST invocation — where
     XLA actually compiles — is timed into ``compile.step.seconds`` and

@@ -431,11 +431,13 @@ def _build_stage1_step(mesh: Mesh, topo: TopologyDescriptor,
     """Compiled stage-1 (ICI) program, served from the shared keyed step
     cache under the STRUCTURAL mesh key — one program per (mesh
     identity, topology, plan signature, width, relay capacity)."""
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                jit_named, program_name)
     _check_hier_mesh(mesh, topo)
     key = ("hier1", mesh_cache_key(mesh), topo.dcn_axis, topo.ici_axis,
            plan, width, int(relay_cap))
-    attrs = {"kind": "hier1", "cap_in": plan.cap_in,
+    name = program_name("tier_ici", plan)
+    attrs = {"kind": "hier1", "program": name, "cap_in": plan.cap_in,
              "relay_cap": int(relay_cap), "width": width,
              "impl": plan.impl, "wire": plan.wire}
 
@@ -444,7 +446,7 @@ def _build_stage1_step(mesh: Mesh, topo: TopologyDescriptor,
         sm = jax.shard_map(_stage1_body(plan, topo, int(relay_cap)),
                            mesh=mesh, in_specs=(spec, spec),
                            out_specs=(spec,) * 3)
-        return jax.jit(sm)
+        return jit_named(sm, name)
 
     return GLOBAL_STEP_CACHE.get(key, build, attrs)
 
@@ -455,11 +457,13 @@ def _build_stage2_step(mesh: Mesh, topo: TopologyDescriptor,
     """Compiled stage-2 (DCN) program — keyed on BOTH capacities (its
     input is the stage-1 relay buffer; its output the final receive
     buffer), same structural mesh key discipline."""
-    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
+                                                jit_named, program_name)
     _check_hier_mesh(mesh, topo)
     key = ("hier2", mesh_cache_key(mesh), topo.dcn_axis, topo.ici_axis,
            plan, width, int(relay_cap), int(out_cap))
-    attrs = {"kind": "hier2", "relay_cap": int(relay_cap),
+    name = program_name("tier_dcn", plan)
+    attrs = {"kind": "hier2", "program": name, "relay_cap": int(relay_cap),
              "cap_out": int(out_cap), "width": width,
              "impl": plan.impl, "wire": plan.wire}
 
@@ -468,7 +472,7 @@ def _build_stage2_step(mesh: Mesh, topo: TopologyDescriptor,
         sm = jax.shard_map(_stage2_body(plan, topo, int(out_cap)),
                            mesh=mesh, in_specs=(spec, spec),
                            out_specs=(spec,) * 4)
-        return jax.jit(sm)
+        return jit_named(sm, name)
 
     return GLOBAL_STEP_CACHE.get(key, build, attrs)
 
@@ -756,7 +760,7 @@ class PendingTieredShuffle(PendingExchangeBase):
     def _assemble(self, rows_out, seg, total):
         from sparkucx_tpu.shuffle.reader import (
             DeviceShuffleReaderResult, LazyShuffleReaderResult,
-            _blocked_map, max_recv_rows)
+            _blocked_map, max_recv_rows, pull_to_host)
         plan = self._plan
         Pn = plan.num_shards
         R = plan.num_partitions
@@ -771,7 +775,7 @@ class PendingTieredShuffle(PendingExchangeBase):
             # plain/ordered: observable delivered-rows requirement
             # for the manager's learned-cap decay (combine's counts
             # are post-merge) — same tiny host read as the flat path
-            seg_np = np.asarray(seg).reshape(Pn, -1, R)
+            seg_np = pull_to_host(seg, "seg").reshape(Pn, -1, R)
             res.recv_rows_needed = max_recv_rows(
                 seg_np, np.asarray(_blocked_map(R, Pn)), Pn)
         if plan.sink == "device":

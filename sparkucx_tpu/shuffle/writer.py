@@ -273,6 +273,12 @@ class MapOutputWriter:
                 f"map {self.map_id}: writer was released (superseded "
                 f"attempt, failed-task retry, or shuffle teardown); its "
                 f"staged rows are gone — obtain a fresh writer")
+        with GLOBAL_TRACER.span("shuffle.write", map_id=self.map_id,
+                                shuffle_id=self.entry.shuffle_id):
+            self._stage(keys, values)
+
+    def _stage(self, keys: np.ndarray, values: Optional[np.ndarray]) -> None:
+        """Validate one batch and copy it into the staging arena."""
         keys = np.ascontiguousarray(keys)
         if keys.ndim != 1:
             raise ValueError("keys must be 1-D")

@@ -30,7 +30,7 @@ Attribution has two matching modes, by span site:
 * spans that structurally CANNOT carry one without threading the trace
   id through reader/distributed signatures (``compile.step``, the
   allgather barrier, ``shuffle.exchange.wait``, ``shuffle.fetch``,
-  ``shuffle.merge``, ``shuffle.spill``) attribute by interval
+  ``shuffle.d2h``, ``shuffle.merge``, ``shuffle.spill``) attribute by interval
   containment inside the exchange wall. Containment is honest on the
   serial read path (reads are collective and ordered); under true
   concurrency an overlapping exchange's untagged span can co-attribute —
@@ -86,6 +86,7 @@ SPAN_PHASE: Dict[str, str] = {
     "shuffle.result": "sink",
     "compile.step": "compile",
     "shuffle.hier.build": "compile",
+    "shuffle.stage": "pack",
     "shuffle.pack": "pack",
     "shuffle.dispatch": "pack",
     "shuffle.wave": "pack",
@@ -99,6 +100,7 @@ SPAN_PHASE: Dict[str, str] = {
     "shuffle.barrier": "barrier_wait",
     "shuffle.merge": "merge",
     "shuffle.fetch": "sink",
+    "shuffle.d2h": "sink",
     "shuffle.settle": "sink",
     "shuffle.spill": "spill",
     "shuffle.verify": "verify",
@@ -110,7 +112,7 @@ SPAN_PHASE: Dict[str, str] = {
 _CONTAINMENT_OK = frozenset((
     "compile.step", "shuffle.barrier", "shuffle.exchange.wait",
     "shuffle.agree",
-    "shuffle.fetch", "shuffle.merge", "shuffle.spill",
+    "shuffle.fetch", "shuffle.d2h", "shuffle.merge", "shuffle.spill",
     "shuffle.hier.build", "shuffle.result",
     # the pending-side redispatch (overflow retry, deferred admission)
     # has no trace id either; the manager's own dispatch spans DO carry

@@ -1,20 +1,26 @@
-"""Structured tracing — spans, Chrome-trace export, device profiler hooks.
+"""Structured tracing — spans, Chrome-trace export, profiler annotations.
 
 The reference has no tracer; its observability is targeted latency logging
 (map-publish overhead per mapId, ref: CommonUcxShuffleBlockResolver.scala:105-106;
 per-request completion ms, ref: UcxWorkerWrapper.scala:101-103; per-endpoint
 fetch bytes+ms, ref: OnBlocksFetchCallback.java:55-56). SURVEY.md §5 calls for
 "the same spirit via structured timers + jax.profiler traces" — this module is
-that: nested wall-clock spans on the host side, optional XLA device traces via
-``jax.profiler``, and a Chrome ``chrome://tracing`` / Perfetto export so a
-shuffle's publish → plan → exchange → group timeline is inspectable.
+that: nested wall-clock spans on the host side, each also a
+``jax.profiler.TraceAnnotation`` so it lands on the profiler's clock beside
+the device's ops whenever a profiler capture runs, and a Chrome
+``chrome://tracing`` / Perfetto export so a shuffle's publish → plan →
+exchange → group timeline is inspectable without one.
 
 Design constraints:
 
-* **Near-zero cost when disabled.** ``span()`` on a disabled tracer returns a
-  shared no-op context manager — no allocation, no clock read. Enable with
-  conf key ``spark.shuffle.tpu.trace.enabled`` (env
-  ``SPARKUCX_TPU_TRACE_ENABLED=1``) or ``Tracer(enabled=True)``.
+* **Profiler annotation always, ring on demand.** Every ``span()`` enters a
+  ``TraceAnnotation`` named after the span, with its attrs as the event's
+  stats; with no capture running that costs under a microsecond. The span
+  ring (timings, attrs, the anatomy fold, the Chrome export) records only
+  when the tracer is enabled: conf key ``spark.shuffle.tpu.trace.enabled``
+  (env ``SPARKUCX_TPU_TRACE_ENABLED=1``) or ``Tracer(enabled=True)``. A
+  disabled span is the annotation itself — no other allocation, no clock
+  read.
 * **Thread-safe, nesting-aware.** Spans nest per-thread (a reduce task's
   ``exchange`` span sits under its ``read`` span); cross-thread events land
   on their own track, like the reference's per-task-thread workers
@@ -26,7 +32,6 @@ Design constraints:
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -66,22 +71,25 @@ class Span:
         return self.dur_us / 1e3
 
 
-class _NullSpan:
-    """Shared no-op context manager for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs):  # parity with _LiveSpan
-        return self
+_ANNOTATION = None
 
 
-_NULL_SPAN = _NullSpan()
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` with the span surface's no-op
+    ``set()`` — what a disabled tracer's ``span()`` returns. Built on
+    first use, so importing this module does not import JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        import jax.profiler
+
+        class _Annotation(jax.profiler.TraceAnnotation):
+            __slots__ = ()
+
+            def set(self, **attrs):  # parity with _LiveSpan
+                return self
+
+        _ANNOTATION = _Annotation
+    return _ANNOTATION
 
 
 class _LiveSpan:
@@ -94,26 +102,23 @@ class _LiveSpan:
         self._annot = None
 
     def set(self, **attrs) -> "_LiveSpan":
+        # ring only: the profiler annotation took its stats at entry
         self.attrs.update(attrs)
         return self
 
     def __enter__(self) -> "_LiveSpan":
         tls = self._tracer._tls
         tls.depth = getattr(tls, "depth", 0) + 1
-        if self._tracer.annotate_device:
-            try:
-                import jax.profiler
-                self._annot = jax.profiler.TraceAnnotation(self.name)
-                self._annot.__enter__()
-            except Exception:  # profiler backend absent; host spans still work
-                self._annot = None
+        # the annotation's own cost falls inside the timed span, so the
+        # anatomy fold does not see it as dark time between spans
         self._t0 = time.perf_counter()
+        self._annot = _annotation_class()(self.name, **self.attrs)
+        self._annot.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._annot.__exit__(*exc)
         t1 = time.perf_counter()
-        if self._annot is not None:
-            self._annot.__exit__(*exc)
         tls = self._tracer._tls
         depth = getattr(tls, "depth", 1)
         tls.depth = depth - 1
@@ -129,16 +134,11 @@ class _LiveSpan:
 
 
 class Tracer:
-    """Span collector with Chrome-trace export.
+    """Span collector with Chrome-trace export; every span is also a
+    profiler annotation (module doc)."""
 
-    ``annotate_device=True`` additionally wraps every span in a
-    ``jax.profiler.TraceAnnotation`` so host spans line up with XLA device
-    ops inside a ``device_trace()`` capture."""
-
-    def __init__(self, enabled: bool = False, capacity: int = 65536,
-                 annotate_device: bool = False):
+    def __init__(self, enabled: bool = False, capacity: int = 65536):
         self.enabled = enabled
-        self.annotate_device = annotate_device
         self._spans: deque = deque(maxlen=capacity)
         self._dropped = 0
         self._published_dropped = 0
@@ -149,9 +149,10 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, **attrs):
-        """Context manager timing one region. No-op when disabled."""
+        """Context manager marking one region: a profiler annotation
+        always, and a timed span in the ring when enabled."""
         if not self.enabled:
-            return _NULL_SPAN
+            return (_ANNOTATION or _annotation_class())(name, **attrs)
         return _LiveSpan(self, name, attrs)
 
     def instant(self, name: str, **attrs) -> None:
@@ -168,9 +169,10 @@ class Tracer:
         name/attrs) they'd be recorded. The exchange WALL span is the
         canonical user: ``ExchangeReport`` stamps its start inside
         ``submit()`` and the settlement callback closes it with the
-        trace id only once the read is fully notified. No-op when
-        disabled — ONE branch, so the disabled read path pays a single
-        attribute check per exchange."""
+        trace id only once the read is fully notified. Ring only — its
+        endpoints are in the past, so no profiler annotation can mark
+        it. No-op when disabled — ONE branch, so the disabled read path
+        pays a single attribute check per exchange."""
         if not self.enabled:
             return
         if t1 is None:
@@ -329,27 +331,6 @@ class Tracer:
                         dropped, self._capacity)
         return len(events)
 
-    # -- device (XLA) traces ----------------------------------------------
-    @contextlib.contextmanager
-    def device_trace(self, logdir: str):
-        """Capture an XLA profiler trace (TensorBoard format) around a
-        region. Host spans recorded inside also appear as annotations when
-        ``annotate_device`` is set. Degrades to host-only tracing when the
-        profiler backend is unavailable (e.g. some CPU builds)."""
-        started = False
-        try:
-            import jax.profiler
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception as e:
-            log.warning("device trace unavailable (%s); host spans only", e)
-        try:
-            yield self
-        finally:
-            if started:
-                import jax.profiler
-                jax.profiler.stop_trace()
-
 
 def _jsonable(v):
     # fast path: span attrs are overwhelmingly primitives, and a doctor/
@@ -371,11 +352,9 @@ GLOBAL_TRACER = Tracer(enabled=False)
 def configure_from_conf(conf) -> Tracer:
     """Wire the global tracer from conf keys:
 
-    ``spark.shuffle.tpu.trace.enabled``   master switch (default off)
+    ``spark.shuffle.tpu.trace.enabled``   span ring on (default off)
     ``spark.shuffle.tpu.trace.capacity``  span ring size (default 65536)
-    ``spark.shuffle.tpu.trace.device``    wrap spans in TraceAnnotations
     """
     GLOBAL_TRACER.enabled = conf.get_bool("trace.enabled", False)
-    GLOBAL_TRACER.annotate_device = conf.get_bool("trace.device", False)
     GLOBAL_TRACER.resize(conf.get_int("trace.capacity", 65536))
     return GLOBAL_TRACER

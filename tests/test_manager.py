@@ -698,6 +698,7 @@ def test_partitions_ready_arrival_order(manager_factory, rng):
         def __init__(self, real):
             self._real = real
             self.shape = real.shape
+            self.nbytes = real.nbytes
 
         def is_ready(self):
             return False            # force the event-driven waiter path

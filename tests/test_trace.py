@@ -25,8 +25,15 @@ def test_disabled_tracer_records_nothing():
 
 
 def test_disabled_span_is_shared_noop():
+    """A disabled span is the profiler annotation itself: no ring span,
+    no clock read, no other allocation."""
+    import jax.profiler
+    from sparkucx_tpu.utils.trace import _LiveSpan
     t = Tracer(enabled=False)
-    assert t.span("a") is t.span("b")  # no per-call allocation
+    sp = t.span("a", rows=3)
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
+    assert not isinstance(sp, _LiveSpan)
+    assert type(sp) is type(t.span("b"))
 
 
 def test_span_timing_and_attrs():
@@ -213,14 +220,90 @@ def test_clear_resets():
     assert t.dropped == 0
 
 
-def test_device_trace_degrades_gracefully(tmp_path):
-    # On CPU the profiler may or may not be available; either way the
-    # context must not raise and host spans must still record.
-    t = Tracer(enabled=True)
-    with t.device_trace(str(tmp_path / "xla")):
-        with t.span("inside"):
+@pytest.fixture()
+def annotations(monkeypatch):
+    """Spy on ``jax.profiler.TraceAnnotation``: every annotation the
+    tracer builds, as [name, attrs, entered, exited]."""
+    import jax.profiler
+    from sparkucx_tpu.utils import trace as trace_mod
+    seen = []
+    real = jax.profiler.TraceAnnotation
+
+    class Spy(real):
+        def __init__(self, name, **attrs):
+            super().__init__(name, **attrs)
+            self._rec = [name, attrs, 0, 0]
+            seen.append(self._rec)
+
+        def __enter__(self):
+            self._rec[2] += 1
+            return super().__enter__()
+
+        def __exit__(self, *exc):
+            self._rec[3] += 1
+            return super().__exit__(*exc)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", None)   # rebuilt on Spy
+    return seen
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_every_span_enters_one_annotation(annotations, enabled):
+    """Spans reach the profiler whether or not the ring is on; the ring
+    records only when enabled."""
+    t = Tracer(enabled=enabled)
+    with t.span("shuffle.outer", shuffle_id=4):
+        with t.span("shuffle.d2h", bytes=64, what="rows"):
             pass
-    assert t.spans("inside")
+    assert annotations == [["shuffle.outer", {"shuffle_id": 4}, 1, 1],
+                           ["shuffle.d2h", {"bytes": 64, "what": "rows"},
+                            1, 1]]
+    assert [s.name for s in t.spans()] == (
+        ["shuffle.d2h", "shuffle.outer"] if enabled else [])
+
+
+def test_disabled_span_set_is_noop(annotations):
+    t = Tracer(enabled=False)
+    with t.span("shuffle.fetch", partition=2) as sp:
+        assert sp.set(bytes=10) is sp
+    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1]]
+    assert t.spans() == []
+
+
+def test_enabled_span_set_updates_ring_only(annotations):
+    t = Tracer(enabled=True)
+    with t.span("shuffle.fetch", partition=2) as sp:
+        sp.set(bytes=10)
+    (span,) = t.spans()
+    assert span.attrs == {"partition": 2, "bytes": 10}
+    assert span.depth == 0 and span.dur_us >= 0
+    # the annotation took its stats at entry
+    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1]]
+
+
+def test_spans_land_in_a_profiler_capture(tmp_path):
+    """With a capture running, a disabled tracer's spans are host events
+    of the trace, named as the span, its attrs as the event's stats."""
+    import glob
+
+    import jax.profiler
+    t = Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("shuffle.read.test"):
+            with t.span("shuffle.d2h", bytes=4096, what="seg"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    events = {ev.name: dict(ev.stats)
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events}
+    assert events["shuffle.read.test"] == {}
+    assert events["shuffle.d2h"] == {"bytes": 4096, "what": "seg"}
 
 
 def test_shuffle_read_emits_spans(manager_factory):
@@ -238,8 +321,9 @@ def test_shuffle_read_emits_spans(manager_factory):
             w.commit(h.num_partitions)
         mgr.read(h)
         names = {s.name for s in tracer.spans()}
-        assert {"shuffle.plan", "shuffle.pack", "shuffle.dispatch",
-                "shuffle.publish"} <= names
+        assert {"shuffle.plan", "shuffle.stage", "shuffle.pack",
+                "shuffle.dispatch", "shuffle.publish", "shuffle.write",
+                "shuffle.d2h"} <= names
         pub = tracer.spans("shuffle.publish")
         assert len(pub) == 4
         assert {s.attrs["map_id"] for s in pub} == {0, 1, 2, 3}
