@@ -608,8 +608,9 @@ class TpuShuffleConf:
     @property
     def combine_compaction(self) -> str:
         """combine_rows end-row compaction formulation: stable | unstable
-        (ops/aggregate.py — bit-identical results, different sort cost;
-        the on-chip A/B lever for the combine path's laggard)."""
+        (ops/aggregate.py — bit-identical results, different sort cost).
+        Only the ``perm`` form reads it: 3-word rows compact in one
+        carried sort on explicit keys (ops/aggregate.combine_form)."""
         v = self._get("a2a.combineCompaction", "stable")
         if v not in ("stable", "unstable"):
             raise ValueError(

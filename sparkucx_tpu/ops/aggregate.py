@@ -16,12 +16,26 @@ aggregation INTO the compiled exchange step, on both sides:
   duplication factor).
 
 Everything is sort + prefix-sum — no scatter (XLA:TPU serializes colliding
-scatters; see ops/partition.counts_from_sorted) and no gather (a [2M]-row
-gather costs ~55 ms on v5e; carried sort operands are nearly free). The grouping
+scatters; see ops/partition.counts_from_sorted). The grouping
 sort is BY (partition, key), which is strictly finer than the
 partition-major exchange sort, so combining replaces that sort instead of
 adding one — and its output is key-sorted within each partition, which is
 the reference pipeline's trailing "sort" step for free.
+
+Two formulations of the same order, chosen at trace time from the row's
+shape (:func:`combine_form`):
+
+* ``carried`` — narrow rows (at most :data:`CARRIED_MAX_OPERANDS` int32
+  sort operands: the three key columns plus one 4-byte value word, i.e.
+  WordCount's 3-word row). The grouping sort and the end-row compaction
+  are each ONE 3-key ``lax.sort`` that carries the row's columns as
+  operands; both share one sort signature, and nothing is gathered.
+  A cap-length 1-D gather costs 0.6-0.7 s on a v5e at 38.9M rows, while
+  a carried operand is nearly free (the benchmark's WordCount cell).
+* ``perm`` — wider rows (TeraSort's 25 words, text WordCount's carried
+  varbytes). XLA:TPU compile time grows with a sort's operand count, so
+  they sort a permutation (:func:`~sparkucx_tpu.ops.partition.
+  lexsort_perm`'s one (key, index) signature) and gather the rows with it.
 
 Key ordering: rows carry int64 keys as two int32 words [lo, hi]
 (shuffle/reader.py transport format). Lexicographic (hi signed, lo
@@ -52,6 +66,14 @@ COMBINERS = ("sum",)
 # backend at import time AND become a closed-over device constant (the
 # lifted-parameter fastpath hazard — see reader.step_body)
 _FLIP = np.int32(-0x80000000)   # two's-complement 0x8000_0000
+# The most int32 operands a combine sort carries before the combine
+# takes the ``perm`` form. 4 = three key columns + one value word (the
+# WordCount row). The carried 1-chip WordCount exchange at 38.9M rows
+# takes 62 s cold to its first result on one v5e, compile included
+# (the perm form 43 s); 4-operand sorts compile twice as long stable.
+CARRIED_MAX_OPERANDS = 4
+# the compaction packs its end-row flag above the partition id
+_FLAG_SHIFT = 30
 
 
 def check_combinable(val_tail, val_dtype, op: str) -> None:
@@ -86,7 +108,7 @@ def keysort_rows(
     (partition, key) keep their input order (the contract promises no
     tie order — Spark's sortByKey does not either — but a stable order
     costs nothing here). The ``ordered`` read path's whole
-    device cost, and the shared head of :func:`combine_rows`.
+    device cost, and the head of :func:`combine_rows`' ``perm`` form.
 
     The sort orders a permutation over the three key columns
     (:func:`~sparkucx_tpu.ops.partition.lexsort_perm`) and gathers the
@@ -123,6 +145,29 @@ def _vals_to_words(vals: jnp.ndarray, vdt: np.dtype, vw: int) -> jnp.ndarray:
         vals.reshape(cap, vw, ratio), jnp.int32)
 
 
+def combine_form(num_parts: int, val_words_n: int, val_dtype,
+                 sum_words: int = 0) -> str:
+    """``carried`` or ``perm``: how :func:`combine_rows` orders rows of
+    this shape (module docstring). ``carried`` when both of its sorts
+    hold at most :data:`CARRIED_MAX_OPERANDS` operands — the grouping
+    sort three keys plus the value words, the compaction three keys plus
+    the prefix-sum lanes and carried words — and the end-row flag packs
+    above the partition id (``num_parts < 2^30``)."""
+    if sum_words <= 0:
+        sum_words = val_words_n
+    lanes = sum_words * (4 // np.dtype(val_dtype).itemsize) \
+        + val_words_n - sum_words
+    narrow = 3 + max(val_words_n, lanes) <= CARRIED_MAX_OPERANDS
+    return "carried" if narrow and num_parts < (1 << _FLAG_SHIFT) else "perm"
+
+
+def _sort3(keys, operands, stable: bool):
+    """ONE sort on three int32 key columns carrying ``operands`` — the
+    carried form's single sort signature."""
+    return jax.lax.sort(tuple(keys) + tuple(operands), num_keys=3,
+                        is_stable=stable)
+
+
 def combine_rows(
     rows: jnp.ndarray,
     part: jnp.ndarray,
@@ -139,6 +184,10 @@ def combine_rows(
     rows       — [cap, W] int32 transport rows (cols 0,1 = key lo,hi; the
                  next ``val_words_n`` cols are the bit-packed value).
     part       — [cap] int32 partition id per row (from the partitioner).
+                 A caller that marks rows with the sentinel id
+                 ``num_parts`` passes them as valid rows (num_valid = cap):
+                 they sort and combine past every real partition and are
+                 not counted in pcounts.
     num_valid  — scalar count of real rows.
     num_parts  — static partition count R.
     val_words_n— value width in int32 words.
@@ -151,8 +200,8 @@ def combine_rows(
                  length-prefixed word bytes of a text WordCount
                  (io/varlen.py pack_counted_varbytes): equal within a key
                  by construction, so any representative is THE value.
-    compaction — the end-row compaction order's keys, bit-identical
-                 results either way (property-tested):
+    compaction — the ``perm`` form's end-row compaction order's keys,
+                 bit-identical results either way (property-tested):
                  ``stable``   — the flag alone; relies on stability to
                                 keep the (part, key) order from the
                                 grouping sort.
@@ -161,6 +210,17 @@ def combine_rows(
                                 (flag, part, key_hi, key_lo). End rows
                                 are unique per (part, key), so explicit
                                 keys restore the exact same order.
+                 The ``carried`` form always sorts on the explicit keys
+                 in one carried sort.
+
+    Forms (module docstring): ``carried`` sorts twice with the columns as
+    operands and gathers nothing; ``perm`` runs :func:`keysort_rows`
+    (three LSD passes and a row gather) and a compaction of one more pass
+    and four column gathers. On one v5e the 38.9M-row WordCount exchange
+    takes 62 s cold to compile and run once as ``carried``, 43 s as
+    ``perm``. Both give
+    bit-identical outputs (property-tested): both keep equal (partition,
+    key) rows in input order wherever that order shows in the result.
 
     Returns (rows_out [cap, W], pcounts [num_parts], n_out [1]):
     rows_out's first n_out rows are one row per distinct (partition, key),
@@ -173,22 +233,43 @@ def combine_rows(
         # sum carried payload bytes on a caller bug, corrupting records
         raise ValueError(
             f"sum_words={sum_words} > value width {val_words_n} words")
+    if compaction not in ("stable", "unstable"):
+        raise ValueError(
+            f"unknown compaction {compaction!r}; want stable|unstable")
     if sum_words <= 0:
         sum_words = val_words_n
+    form = combine_form(num_parts, val_words_n, vdt, sum_words)
     carry_n = val_words_n - sum_words
     cap, W = rows.shape
     idx = jnp.arange(cap, dtype=jnp.int32)
     valid = idx < num_valid
+    # the carried sorts keep equal (partition, key) rows in input order
+    # only where that order shows in the result: float sums round by it
+    # and a carried payload lane's representative is its last row. Integer
+    # sums are exact in any order (int32 lanes wrap mod 2^32), and an
+    # unstable sort compiles in about half the time on XLA:TPU.
+    stable = bool(carry_n) or not np.issubdtype(vdt, np.integer)
 
-    # ---- one grouping sort: (partition, key_hi, key_lo-as-unsigned) ----
-    spart, srows, _ = keysort_rows(rows, part, num_valid, num_parts)
+    # ---- one grouping order: (partition, key_hi, key_lo-as-unsigned) ----
+    if form == "carried":
+        # padding takes the sentinel partition and a zero key, exactly
+        # the keys keysort_rows orders by, so both forms give one order
+        pkey = jnp.where(valid, part.astype(jnp.int32), jnp.int32(num_parts))
+        out = _sort3((pkey, jnp.where(valid, rows[:, 1], 0),
+                      jnp.where(valid, rows[:, 0] ^ _FLIP, 0)),
+                     [rows[:, 2 + j] for j in range(val_words_n)], stable)
+        spart, khi, klo = out[0], out[1], out[2] ^ _FLIP
+        vwords = jnp.stack(out[3:], axis=1)               # [cap, vw]
+    else:
+        spart, srows, _ = keysort_rows(rows, part, num_valid, num_parts)
+        klo, khi = srows[:, 0], srows[:, 1]
+        vwords = srows[:, 2:2 + val_words_n]
 
     # ---- segment ENDS: last valid row, or row before a (part, key)
     # change. Ends (not starts) are the anchor because the inclusive
     # prefix sum AT an end row, differenced against the previous end's,
     # IS the segment sum — consecutive in sorted order, no index gather.
-    key_eq = (srows[:, 0] == jnp.roll(srows[:, 0], 1)) \
-        & (srows[:, 1] == jnp.roll(srows[:, 1], 1))
+    key_eq = (klo == jnp.roll(klo, 1)) & (khi == jnp.roll(khi, 1))
     part_eq = spart == jnp.roll(spart, 1)
     is_start = valid & ~(key_eq & part_eq)
     is_start = is_start.at[0].set(num_valid > 0)
@@ -196,39 +277,48 @@ def combine_rows(
     is_end = valid & (jnp.roll(is_start, -1) | (idx == num_valid - 1))
 
     # ---- inclusive prefix sums of the (masked) summed lanes -------------
-    vals = _words_to_vals(srows[:, 2:2 + sum_words], vdt)
+    vals = _words_to_vals(vwords[:, :sum_words], vdt)
     acc_dt = jnp.float32 if np.issubdtype(vdt, np.floating) else jnp.int32
     acc = jnp.where(valid[:, None], vals.astype(acc_dt), 0)
     incl = jnp.cumsum(acc, axis=0)                        # [cap, m]
+    carried = vwords[:, sum_words:]                       # [cap, carry_n]
 
-    # ---- compact end rows to the front, CARRYING their columns ----------
-    # One stable order moves every segment-end row (keys, partition,
-    # prefix-sum lanes, carried payload words) to the front in
-    # (partition, key) order, and one gather per column family
-    # materializes it. Carried value lanes ride the same gather: the end
-    # row IS the representative, no differencing.
+    # ---- compact end rows to the front, in (partition, key) order -------
+    # Every segment-end row (keys, partition, prefix-sum lanes, carried
+    # payload words) moves to the front; the end row IS the carried
+    # lanes' representative, no differencing. Dead (flag=1) rows land
+    # past n_out, where every lane is masked to zero below.
     flag = jnp.where(is_end, 0, 1).astype(jnp.int32)
-    if compaction == "unstable":
-        # explicit (flag, part, key) keys — end rows are unique per
-        # (part, key), so the order equals the stable flag order below;
-        # the lo word is flipped for unsigned compare (module docstring).
-        # flag ({0,1}) packs into bit 30 above part when part < 2^30.
-        # Dead (flag=1) rows land past n_out, where every lane is
-        # masked to zero below.
-        lo_key = srows[:, 0] ^ jnp.int32(_FLIP)
-        keys = (((flag << jnp.int32(30)) | spart, srows[:, 1], lo_key)
-                if num_parts < (1 << 30)
-                else (flag, spart, srows[:, 1], lo_key))
-    elif compaction == "stable":
-        keys = (flag,)
+    lo_key = klo ^ jnp.int32(_FLIP)
+    if form == "carried":
+        # explicit keys: end rows are unique per (part, key), so this is
+        # the grouping order; the flag packs above the partition id
+        # (combine_form admits num_parts < 2^30 only), the prefix sums
+        # travel as int32 bits so both sorts keep one signature
+        m = incl.shape[1]
+        lanes = jax.lax.bitcast_convert_type(incl, jnp.int32)
+        out = _sort3(((flag << jnp.int32(_FLAG_SHIFT)) | spart, khi, lo_key),
+                     [lanes[:, j] for j in range(m)]
+                     + [carried[:, j] for j in range(carry_n)], stable)
+        epart = out[0] & jnp.int32((1 << _FLAG_SHIFT) - 1)
+        khi, klo = out[1], out[2] ^ _FLIP
+        ends_incl = jax.lax.bitcast_convert_type(
+            jnp.stack(out[3:3 + m], axis=1), incl.dtype)   # [cap, m]
+        carried = jnp.stack(out[3 + m:], axis=1) if carry_n else carried
     else:
-        raise ValueError(
-            f"unknown compaction {compaction!r}; want stable|unstable")
-    perm = lexsort_perm(keys)
-    epart = jnp.take(spart, perm)
-    klo = jnp.take(srows[:, 0], perm)
-    khi = jnp.take(srows[:, 1], perm)
-    ends_incl = jnp.take(incl, perm, axis=0)              # [cap, m]
+        if compaction == "unstable":
+            keys = (((flag << jnp.int32(_FLAG_SHIFT)) | spart, khi, lo_key)
+                    if num_parts < (1 << _FLAG_SHIFT)
+                    else (flag, spart, khi, lo_key))
+        else:
+            keys = (flag,)
+        perm = lexsort_perm(keys)
+        epart = jnp.take(spart, perm)
+        klo = jnp.take(klo, perm)
+        khi = jnp.take(khi, perm)
+        ends_incl = jnp.take(incl, perm, axis=0)          # [cap, m]
+        if carry_n:
+            carried = jnp.take(carried, perm, axis=0)     # [cap, carry_n]
 
     # ---- segment sums = first differences of end-row prefix sums --------
     live = idx < n_out
@@ -240,8 +330,7 @@ def combine_rows(
     pieces = [jnp.stack([klo, khi], axis=1),
               _vals_to_words(seg_sum, vdt, sum_words)]
     if carry_n:
-        pieces.append(jnp.take(                           # [cap, carry_n]
-            srows[:, 2 + sum_words:2 + val_words_n], perm, axis=0))
+        pieces.append(carried)
     if W - 2 - val_words_n:
         pieces.append(jnp.zeros((cap, W - 2 - val_words_n), jnp.int32))
     rows_out = jnp.concatenate(pieces, axis=1)

@@ -67,11 +67,17 @@ def lexsort_perm(keys) -> jnp.ndarray:
     Built as LSD passes that all share ONE sort signature — a stable
     1-key sort of (int32 key, int32 index) — and XLA:TPU compiles that
     sort once per program however many passes use it. Compile cost on
-    the chip tracks the number of sort OPERANDS, not the data: a 28-
-    operand row-carrying sort of 100k rows compiles for over 5 minutes
-    and a 4-operand 3-key sort of 32M rows in 146 s, while three passes
-    of this signature at 32M compile in 40 s (PR 21, compiled for a
-    described v5e). Callers gather their rows with the permutation."""
+    the chip tracks the number of sort OPERANDS and stability: at 38.9M
+    rows, compiled for a described v5e, this signature takes 40 s, a
+    4-operand 3-key sort 117 s stable and 62 s unstable, and a 28-operand
+    row-carrying sort of 100k rows over 5 minutes. Callers gather their
+    rows with the permutation: the ordered read's :func:`~sparkucx_tpu.
+    ops.aggregate.keysort_rows` for every width, and the combine for rows
+    wider than 3 words (TeraSort's 25, text WordCount's varbytes). The
+    combine's 3-word rows sort with their columns carried instead
+    (:func:`~sparkucx_tpu.ops.aggregate.combine_form`): the WordCount
+    exchange's first dispatch takes 62 s cold on one v5e, compile
+    included, against 43 s in this permutation form."""
     keys = [k.astype(jnp.int32) for k in keys]
     perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
     for i, k in enumerate(reversed(keys)):

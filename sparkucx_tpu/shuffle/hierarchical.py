@@ -52,7 +52,7 @@ def _build_hier_step(mesh: Mesh, dcn_axis: str, ici_axis: str,
     fresh Mesh object over the same devices) reuses its compiled
     programs instead of recompiling both tiers."""
     from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
-                                                program_name)
+                                                combine_attrs, program_name)
     from sparkucx_tpu.shuffle.topology import mesh_cache_key
     from sparkucx_tpu.utils.trace import GLOBAL_TRACER
 
@@ -68,7 +68,7 @@ def _build_hier_step(mesh: Mesh, dcn_axis: str, ici_axis: str,
                                               width),
             {"kind": "hier", "program": name, "cap_in": plan.cap_in,
              "cap_out": plan.cap_out, "width": width, "impl": plan.impl,
-             "wire": plan.wire})
+             "wire": plan.wire, **combine_attrs(plan)})
 
 
 def _build_hier_step_uncached(mesh: Mesh, dcn_axis: str, ici_axis: str,

@@ -61,8 +61,8 @@ class ShufflePlan:
     # bytes — io/varlen.py). 0 = sum the whole value row.
     combine_sum_words: int = 0
     # combine_rows end-row compaction formulation (stable | unstable) —
-    # bit-identical output, different TPU sort cost; conf-selectable for
-    # the on-chip A/B (a2a.combineCompaction).
+    # bit-identical output, different TPU sort cost; conf-selectable
+    # (a2a.combineCompaction), read by the combine's ``perm`` form only.
     combine_compaction: str = "stable"
     # device key sort: partitions come back key-sorted (signed int64
     # order) — the "sort" half of the reference reduce pipeline's stock

@@ -505,7 +505,7 @@ def _build_step(mesh: Mesh, axis: str, plan: ShufflePlan, width: int):
     builder and manager.warmup. The pipeline itself is
     :func:`step_body`."""
     from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
-                                                program_name)
+                                                combine_attrs, program_name)
     # the plan rides the key whole, so the wire tier (plan.wire — part
     # of plan.family too) names its own compiled program per shape
     # family: raw and int8 runs of one shape never collide on a step
@@ -515,7 +515,7 @@ def _build_step(mesh: Mesh, axis: str, plan: ShufflePlan, width: int):
         lambda: _build_step_uncached(mesh, axis, plan, width),
         {"kind": "flat", "program": name, "cap_in": plan.cap_in,
          "cap_out": plan.cap_out, "width": width, "impl": plan.impl,
-         "wire": plan.wire})
+         "wire": plan.wire, **combine_attrs(plan)})
 
 
 def _build_step_uncached(mesh: Mesh, axis: str, plan: ShufflePlan,

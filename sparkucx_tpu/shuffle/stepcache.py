@@ -147,6 +147,18 @@ def program_name(kind: str, plan=None) -> str:
     return f"shuffle_{kind}_{mode}"
 
 
+def combine_attrs(plan) -> dict:
+    """``{"combine_form": "carried" | "perm"}`` for a combining plan, else
+    empty: how the program's ``combine_rows`` orders its rows
+    (ops/aggregate.combine_form), carried on its ``compile.step`` span."""
+    if not plan.combine:
+        return {}
+    from sparkucx_tpu.ops.aggregate import combine_form
+    return {"combine_form": combine_form(
+        plan.num_partitions, plan.combine_words, plan.combine_dtype,
+        plan.combine_sum_words)}
+
+
 def jit_named(fn: Callable, name: str, **jit_kwargs) -> Callable:
     """``jax.jit(fn)`` compiled under ``name`` (jit names its program
     after the function, which for a shard_map is the inner body's)."""

@@ -432,14 +432,15 @@ def _build_stage1_step(mesh: Mesh, topo: TopologyDescriptor,
     cache under the STRUCTURAL mesh key — one program per (mesh
     identity, topology, plan signature, width, relay capacity)."""
     from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
-                                                jit_named, program_name)
+                                                combine_attrs, jit_named,
+                                                program_name)
     _check_hier_mesh(mesh, topo)
     key = ("hier1", mesh_cache_key(mesh), topo.dcn_axis, topo.ici_axis,
            plan, width, int(relay_cap))
     name = program_name("tier_ici", plan)
     attrs = {"kind": "hier1", "program": name, "cap_in": plan.cap_in,
              "relay_cap": int(relay_cap), "width": width,
-             "impl": plan.impl, "wire": plan.wire}
+             "impl": plan.impl, "wire": plan.wire, **combine_attrs(plan)}
 
     def build():
         spec = P((topo.dcn_axis, topo.ici_axis))
@@ -458,14 +459,15 @@ def _build_stage2_step(mesh: Mesh, topo: TopologyDescriptor,
     input is the stage-1 relay buffer; its output the final receive
     buffer), same structural mesh key discipline."""
     from sparkucx_tpu.shuffle.stepcache import (GLOBAL_STEP_CACHE,
-                                                jit_named, program_name)
+                                                combine_attrs, jit_named,
+                                                program_name)
     _check_hier_mesh(mesh, topo)
     key = ("hier2", mesh_cache_key(mesh), topo.dcn_axis, topo.ici_axis,
            plan, width, int(relay_cap), int(out_cap))
     name = program_name("tier_dcn", plan)
     attrs = {"kind": "hier2", "program": name, "relay_cap": int(relay_cap),
              "cap_out": int(out_cap), "width": width,
-             "impl": plan.impl, "wire": plan.wire}
+             "impl": plan.impl, "wire": plan.wire, **combine_attrs(plan)}
 
     def build():
         spec = P((topo.dcn_axis, topo.ici_axis))

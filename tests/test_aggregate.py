@@ -7,6 +7,8 @@ so these tests pin (a) the kernel against numpy and (b) the end-to-end
 combined read against an uncombined read of the same shuffle.
 """
 
+import zlib
+
 import numpy as np
 import pytest
 
@@ -232,24 +234,33 @@ def test_combined_read_hierarchical():
         node.close()
 
 
-def test_combined_read_single_shard_skips_receive_merge():
+@pytest.mark.parametrize("width,want_sorts,form",
+                         [(3, 2, "carried"), (4, 4, "perm")])
+def test_combined_read_single_shard_skips_receive_merge(width, want_sorts,
+                                                        form):
     """On a 1-shard exchange the step returns the map-side combine's rows
     directly (there is nothing to merge); results must match the same
     job's multi-shard oracle semantics, and the compiled HLO must contain
-    exactly ONE grouping sort chain (no second combine)."""
+    exactly ONE combine's sorts (no second combine): a 3-word row takes
+    the carried form — two carried sorts and no cap-length gather — and
+    a 4-word row the permutation form's three LSD passes + compaction."""
+    import re
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
+    from sparkucx_tpu.ops.aggregate import combine_form
     from sparkucx_tpu.shuffle.plan import ShufflePlan
     from sparkucx_tpu.shuffle.reader import (pack_rows, step_body,
                                              unpack_rows)
 
     R, n = 8, 500
+    vw = width - 2
+    assert combine_form(R, vw, np.int32) == form
     rng = np.random.default_rng(3)
     keys = rng.integers(0, 29, size=n)                 # heavy duplication
-    vals = rng.integers(-50, 50, size=(n, 2)).astype(np.int32)
-    width = 2 + 2
+    vals = rng.integers(-50, 50, size=(n, vw)).astype(np.int32)
     rows = pack_rows(keys.astype(np.int64), vals, width)
     cap = 512
     payload = np.zeros((cap, width), np.int32)
@@ -257,7 +268,7 @@ def test_combined_read_single_shard_skips_receive_merge():
 
     plan = ShufflePlan(num_shards=1, num_partitions=R, cap_in=cap,
                        cap_out=768, impl="auto", combine="sum",
-                       combine_words=2, combine_dtype="<i4")
+                       combine_words=vw, combine_dtype="<i4")
     mesh1 = Mesh(np.array(jax.devices()[:1]), ("x",))
     jitted = jax.jit(jax.shard_map(
         step_body(plan, "x"), mesh=mesh1, in_specs=(P("x"), P("x")),
@@ -270,7 +281,7 @@ def test_combined_read_single_shard_skips_receive_merge():
     for k, v in zip(keys.tolist(), vals):
         want[k] = want.get(k, 0) + v.astype(np.int64)
     got_k, got_v = unpack_rows(
-        np.asarray(out_rows)[:int(np.asarray(total)[0])], (2,), np.int32)
+        np.asarray(out_rows)[:int(np.asarray(total)[0])], (vw,), np.int32)
     assert len(got_k) == len(want)
     from sparkucx_tpu.ops.partition import hash32
     import jax.numpy as _jnp
@@ -282,15 +293,19 @@ def test_combined_read_single_shard_skips_receive_merge():
     pc = np.asarray(seg).reshape(R)
     counts = np.bincount(parts, minlength=R)
     np.testing.assert_array_equal(pc, counts)
-    # exactly one combine chain: the map-side grouping (three lexsort
-    # passes) + compaction (one) sorts only — a receive-side merge would
-    # add four more "stablehlo.sort" ops
+    # exactly one combine chain — a receive-side merge would add its
+    # sorts again
     txt = jitted.lower(
         jax.ShapeDtypeStruct((cap, width), jnp.int32),
         jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
     nsorts = txt.count("stablehlo.sort")
-    assert nsorts == 4, \
-        f"expected 4 sorts (grouping + compaction), got {nsorts}"
+    assert nsorts == want_sorts, \
+        f"expected {want_sorts} sorts (grouping + compaction), got {nsorts}"
+    # a gather indexed by a cap-length index vector moves cap rows
+    cap_gathers = re.findall(
+        r'"stablehlo\.gather".*: \(tensor<[^>]*>, tensor<%dx1xi32>\)' % cap,
+        txt)
+    assert bool(cap_gathers) == (form == "perm"), cap_gathers
 
 
 def test_combine_compaction_variants_agree(mesh8, rng):
@@ -320,3 +335,67 @@ def test_combine_compaction_variants_agree(mesh8, rng):
     n = outs["stable"][2]
     np.testing.assert_array_equal(outs["stable"][0][:n],
                                   outs["unstable"][0][:n])
+
+
+# keys around the lo/hi word split: signed int64 order must hold across it
+_SPLIT_KEYS = np.array([-(1 << 40), -(1 << 32) - 1, -(1 << 32), -(1 << 31) - 1,
+                        -(1 << 31), -2, -1, 0, 1, (1 << 31) - 1, 1 << 31,
+                        (1 << 32) - 1, 1 << 32, 1 << 40], np.int64)
+
+
+@pytest.mark.parametrize("vdtype", [np.int32, np.float32])
+@pytest.mark.parametrize("case", ["duplicates", "negative_keys", "empty",
+                                  "one_row", "sentinel_partition",
+                                  "carried_lane"])
+def test_combine_forms_bit_identical(vdtype, case, monkeypatch):
+    """The carried form (columns ride the sorts) and the permutation form
+    (lexsort passes + gathers) give bit-identical (rows_out, pcounts,
+    n_out): equal (partition, key) rows keep their input order wherever
+    that order shows in the result, float sums included. The carried form
+    is also pinned one operand past its bound, with a carried payload
+    lane. The bound is moved to force each form."""
+    from sparkucx_tpu.ops import aggregate
+
+    cap, R = 256, 8
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    width, sum_words = (4, 1) if case == "carried_lane" else (3, 0)
+    vw = width - 2
+    n_valid = {"empty": 0, "one_row": 1, "sentinel_partition": cap}.get(
+        case, 200)
+    if case == "negative_keys":
+        keys = rng.choice(_SPLIT_KEYS, size=cap)
+    else:
+        keys = rng.integers(-6, 6, size=cap).astype(np.int64) \
+            * rng.choice(np.array([1, 1 << 31, 1 << 33]), size=cap)
+    rows = np.zeros((cap, width), np.int32)
+    rows[:, :2] = keys.view(np.int32).reshape(-1, 2)
+    if vdtype == np.float32:
+        rows[:, 2] = rng.standard_normal(cap).astype(np.float32).view(
+            np.int32)
+    else:
+        rows[:, 2] = rng.integers(-(1 << 31), (1 << 31) - 1, size=cap)
+    if case == "carried_lane":
+        rows[:, 3] = (keys * 7 + 3).astype(np.int32)   # constant per key
+    part = rng.integers(0, R, size=cap).astype(np.int32)
+    if case == "sentinel_partition":
+        part[rng.random(cap) < 0.25] = R     # marked rows, passed as valid
+    if case != "carried_lane":
+        assert aggregate.combine_form(R, vw, vdtype) == "carried"
+
+    def run(max_operands, compaction="stable"):
+        monkeypatch.setattr(aggregate, "CARRIED_MAX_OPERANDS", max_operands)
+        assert aggregate.combine_form(R, vw, vdtype, sum_words) == \
+            ("carried" if max_operands else "perm")
+        fn = jax.jit(lambda r, p, n: combine_rows(
+            r, p, n, R, vw, vdtype, sum_words=sum_words,
+            compaction=compaction))
+        return [np.asarray(x) for x in fn(rows, part, jnp.int32(n_valid))]
+
+    carried = run(1 + width)
+    for perm in (run(0), run(0, "unstable")):
+        for a, b in zip(carried, perm):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a.view(np.int32), b.view(np.int32))
+    n_out = int(carried[2][0])
+    assert (n_out == 0) == (n_valid == 0)
+    assert not carried[0][n_out:].any()

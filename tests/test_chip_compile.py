@@ -205,6 +205,43 @@ def test_native_exchange_step_compiles_on_2x2(topo):
     assert "replica_groups={{0,1,2,3}}" in text.replace(" ", "")
 
 
+def test_wordcount_combine_step_compiles_carried(topo):
+    """The one-chip WordCount exchange: its map-side combine sorts the
+    3-word (key, count) rows twice with their columns carried, one sort
+    signature, and gathers nothing of the capacity's length. 16,384 rows
+    already take XLA:TPU's large-sort code path (a capacity of 4,096
+    compiles ten times faster), while the cell's 38.9M rows would take a
+    minute to compile here."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sparkucx_tpu.config import TpuShuffleConf
+    from sparkucx_tpu.shuffle.plan import make_plan
+    from sparkucx_tpu.shuffle.reader import _build_step_uncached
+    plan = dataclasses.replace(
+        make_plan(np.array([1 << 14]), 1, 64, TpuShuffleConf(use_env=False)),
+        combine="sum", combine_words=WORDCOUNT_WIDTH - 2, combine_dtype="<i4",
+        pallas_interpret=False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("shuffle",))
+    sh = NamedSharding(mesh, P("shuffle"))
+    step = _build_step_uncached(mesh, "shuffle", plan, WORDCOUNT_WIDTH)
+    text = step.lower(
+        jax.ShapeDtypeStruct((plan.cap_in, WORDCOUNT_WIDTH), jnp.int32,
+                             sharding=sh),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=sh)
+    ).compile().as_text()
+    col = "s32[%d]" % plan.cap_in
+    # an instruction's result shape is the text between "=" and the op
+    shapes = {op: [line.split(" = ", 1)[1].split(f" {op}(")[0]
+                   for line in text.splitlines() if f" {op}(" in line]
+              for op in ("sort", "gather")}
+    assert [s.count(col) for s in shapes["sort"]] == [4, 4], shapes["sort"]
+    assert not [s for s in shapes["gather"] if col in s], shapes["gather"]
+
+
 def test_chip_smoke_refuses_without_a_tpu(tmp_path):
     """chip_smoke.py on the CPU backend exits non-zero and prints no
     result, and a --rows rehearsal runs both phases on their oracles

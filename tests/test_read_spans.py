@@ -26,7 +26,7 @@ def _manager(manager_factory, chips, conf=None):
     return mgr
 
 
-def _stage(mgr, partitioner="hash", bounds=None, seed=0):
+def _stage(mgr, partitioner="hash", bounds=None, seed=0, vw=VW):
     rng = np.random.default_rng(seed)
     _SID[0] += 1
     h = mgr.register_shuffle(_SID[0], M, R, partitioner=partitioner,
@@ -34,7 +34,7 @@ def _stage(mgr, partitioner="hash", bounds=None, seed=0):
     for m in range(M):
         w = mgr.get_writer(h, m)
         w.write(rng.integers(0, 1 << 40, size=N, dtype=np.int64),
-                rng.integers(0, 1 << 20, size=(N, VW)).astype(np.int32))
+                rng.integers(0, 1 << 20, size=(N, vw)).astype(np.int32))
         w.commit(R)
     return h
 
@@ -93,6 +93,25 @@ def test_exchange_program_named_by_mode(manager_factory, mode):
         pending.result()
     finally:
         mgr.unregister_shuffle(h.shuffle_id)
+
+
+@pytest.mark.parametrize("vw,form", [(1, "carried"), (VW, "perm")])
+def test_combine_program_records_its_form(manager_factory, vw, form):
+    """A combining exchange's ``compile.step`` span carries which form its
+    combine takes: a 3-word (key, count) row sorts with its columns
+    carried, a wider row by permutation and gather."""
+    mgr = _manager(manager_factory, 1)
+    h, h_plain = _stage(mgr, vw=vw), _stage(mgr, vw=vw)
+    try:
+        pending = mgr.submit(h, combine="sum")
+        assert pending._step._attrs["combine_form"] == form
+        pending.result()
+        plain = mgr.submit(h_plain)
+        assert "combine_form" not in plain._step._attrs
+        plain.result()
+    finally:
+        mgr.unregister_shuffle(h.shuffle_id)
+        mgr.unregister_shuffle(h_plain.shuffle_id)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
