@@ -558,7 +558,9 @@ def exchange_run(jax, rows_log2, val_words, k1, k2, reps,
                 # data dependency so XLA cannot hoist or dedupe the steps
                 carry = carry ^ lax.optimization_barrier(
                     out[0:1, :]).astype(carry.dtype)
-                return carry, ovf
+                # the flag is the first word (a combining step's last
+                # output also carries its row counts)
+                return carry, ovf[:1] != 0
             carry, ovfs = lax.scan(body, payload, None, length=k)
             return carry[0:1, 0], jnp.any(ovfs).reshape(1)
         return jax.jit(jax.shard_map(

@@ -609,6 +609,9 @@ def _segreduce_pallas(rows, part, num_parts: int, sum_words: int,
                         pltpu.VMEM((1, sum_words), acc_dt),
                         pltpu.VMEM((1, width), jnp.int32)],
         interpret=interpret,
+        # the name its op carries in the compiled program, so a
+        # profiler trace gives the kernel's own device time
+        name="segreduce_blocked",
         **kw,
     )(xt)
     # compact the per-tile heads (each block's live rows are a prefix)

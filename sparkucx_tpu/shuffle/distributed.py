@@ -42,7 +42,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from sparkucx_tpu.shuffle.plan import ShufflePlan, wire_row_words
 from sparkucx_tpu.shuffle.reader import (
     LazyShuffleReaderResult, PendingExchangeBase, ShuffleReaderResult,
-    _blocked_map, _build_step, max_recv_rows, seeded_nvalid)
+    _blocked_map, _build_step, max_recv_rows, overflow_flags,
+    seeded_nvalid)
 from sparkucx_tpu.shuffle.topology import (PendingTieredShuffle,
                                            TierHooks,
                                            TopologyDescriptor)
@@ -502,7 +503,7 @@ class PendingDistributedShuffle(PendingExchangeBase):
                     tier="ici+dcn" if self._hier_mesh is not None
                     else "dcn"):
                 mine = current_watchdog().call(
-                    lambda: any(bool(np.asarray(s.data).any())
+                    lambda: any(bool(overflow_flags(s.data, 1)[0])
                                 for s in ovf.addressable_shards),
                     # the fused hierarchical step cannot split its tiers
                     # under separate deadlines (shuffle/topology.py

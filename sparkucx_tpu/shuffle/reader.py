@@ -172,6 +172,24 @@ def _wire_ragged_shuffle(plan: ShufflePlan, send, sizes, axis, seed,
     return ShuffleResult(data, r.recv_sizes, r.total, r.overflow)
 
 
+def combine_status(overflow, rows_recv, rows_out):
+    """A combining step's last output, [3] int32 per shard: the overflow
+    flag first (where the other steps put their [1] bool flag), then the
+    rows this shard received (what the map-side combines put on the wire
+    to it) and its rows after the receive-side reduce. The host reads it
+    where it reads the flag, so the counts take no transfer of their own
+    (``overflow_flags``)."""
+    return jnp.concatenate([jnp.reshape(x, (1,)).astype(jnp.int32)
+                            for x in (overflow, rows_recv, rows_out)])
+
+
+def overflow_flags(last, num_shards: int) -> np.ndarray:
+    """[num_shards] bool overflow verdicts from a step's last output on
+    the host: [1] bool a shard, or a combining step's ``combine_status``,
+    whose first word is the flag."""
+    return np.asarray(last).reshape(num_shards, -1)[:, 0] != 0
+
+
 def step_body(plan: ShufflePlan, axis: str):
     """The per-shard exchange step (call under shard_map over ``axis``).
 
@@ -288,7 +306,8 @@ def step_body(plan: ShufflePlan, axis: str):
                 # (partition, key), key-sorted — a receive-side merge
                 # would re-sort the (1.5x larger) receive buffer to merge
                 # nothing. rcounts IS the per-partition output counts.
-                return r.data, rcounts.reshape(1, R), r.total, r.overflow
+                return r.data, rcounts.reshape(1, R), r.total, \
+                    combine_status(r.overflow, r.total, r.total)
             # reduce-side combine: merge the per-sender segments' rows by
             # key before D2H — one run per partition, so the seg matrix is
             # this shard's OWN combined counts ([1, R] per shard)
@@ -324,8 +343,9 @@ def step_body(plan: ShufflePlan, axis: str):
                     plan.combine_words, np.dtype(plan.combine_dtype),
                     plan.combine, sum_words=plan.combine_sum_words,
                     compaction=plan.combine_compaction)
-            return rows_out, pcounts.reshape(1, R), \
-                n_out.astype(r.total.dtype), r.overflow
+            n_out = n_out.astype(r.total.dtype)
+            return rows_out, pcounts.reshape(1, R), n_out, \
+                combine_status(r.overflow, r.total, n_out)
         if plan.ordered:
             if Pn == 1:
                 # already (partition, key)-sorted on the send side above
@@ -492,6 +512,8 @@ def _pallas_step_body(plan: ShufflePlan, axis: str):
         # total from pcounts, not the sort's group count: the sentinel
         # partition's groups must not inflate the reported row count
         total = pcounts.sum().astype(jnp.int32).reshape(1)
+        if plan.combine:
+            ovf = combine_status(ovf, recv_real.sum(), total)
         return rows_out, pcounts.reshape(1, R), total, ovf
 
     return step
@@ -1491,12 +1513,14 @@ class WavedShuffleReaderResult(ShuffleReaderResult):
             return self._waves[0]._partition_block(r, shard)
         if (self._plan.combine or self._plan.ordered) and len(blocks) > 1:
             with GLOBAL_TRACER.span("shuffle.merge", waves=len(blocks),
-                                    impl="host"):
+                                    impl="host") as sp:
                 if self._plan.combine:
                     block = combine_packed_rows(
                         blocks, self._plan.combine_words,
                         np.dtype(self._plan.combine_dtype),
                         self._plan.combine_sum_words)
+                    sp.set(rows_in=sum(b.shape[0] for b in blocks),
+                           rows_out=int(block.shape[0]))
                 else:
                     block = merge_sorted_rows(blocks)
         elif len(blocks) == 1:
@@ -1900,8 +1924,17 @@ class PendingShuffle(PendingExchangeBase):
             # the dispatched collective drains — the single-process flat
             # transfer wait (single-slice mesh => the ICI tier;
             # containment-matched, no trace id on this signature)
-            with GLOBAL_TRACER.span("shuffle.exchange.wait", tier="ici"):
-                overflowed = bool(np.asarray(ovf).any())
+            with GLOBAL_TRACER.span("shuffle.exchange.wait",
+                                    tier="ici") as sp:
+                last = np.asarray(ovf)
+                overflowed = bool(overflow_flags(
+                    last, self._plan.num_shards).any())
+                if self._plan.combine and not overflowed:
+                    # the combine's row counts rode the flag's transfer
+                    status = last.reshape(self._plan.num_shards, -1)
+                    sp.set(rows_in=int(self._nvalid_host.sum()),
+                           rows_wire=int(status[:, 1].sum()),
+                           rows_out=int(status[:, 2].sum()))
             if not overflowed:
                 break
             if self._attempt >= self._plan.max_retries:

@@ -15,7 +15,8 @@ Design constraints:
 
 * **Profiler annotation always, ring on demand.** Every ``span()`` enters a
   ``TraceAnnotation`` named after the span, with its attrs as the event's
-  stats; with no capture running that costs under a microsecond. The span
+  stats, and ``set()`` on an open span adds stats to it; with no capture
+  running that costs under a microsecond. The span
   ring (timings, attrs, the anatomy fold, the Chrome export) records only
   when the tracer is enabled: conf key ``spark.shuffle.tpu.trace.enabled``
   (env ``SPARKUCX_TPU_TRACE_ENABLED=1``) or ``Tracer(enabled=True)``. A
@@ -86,6 +87,8 @@ def _annotation_class():
             __slots__ = ()
 
             def set(self, **attrs):  # parity with _LiveSpan
+                # more stats on the event (a no-op with no capture on)
+                self.set_metadata(**attrs)
                 return self
 
         _ANNOTATION = _Annotation
@@ -102,8 +105,9 @@ class _LiveSpan:
         self._annot = None
 
     def set(self, **attrs) -> "_LiveSpan":
-        # ring only: the profiler annotation took its stats at entry
         self.attrs.update(attrs)
+        if self._annot is not None:
+            self._annot.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "_LiveSpan":

@@ -94,7 +94,10 @@ def test_segment_reduce_kernel_compiles(one_chip, caps):
     def fn(r, p):
         return segment_reduce_rows(r, p, 256, 1, np.int32, impl="pallas",
                                    interpret=False)
-    assert "tpu_custom_call" in _compiled_text(fn, rows, part)
+    text = _compiled_text(fn, rows, part)
+    assert "tpu_custom_call" in text
+    # named, so a profiler trace finds the kernel's own ops
+    assert "%segreduce_blocked" in text
 
 
 def test_fused_int8_reduce_kernel_compiles(one_chip, caps):
@@ -203,6 +206,39 @@ def test_native_exchange_step_compiles_on_2x2(topo):
     ).compile().as_text()
     assert "ragged-all-to-all" in text
     assert "replica_groups={{0,1,2,3}}" in text.replace(" ", "")
+
+
+def test_wordcount_combine_step_compiles_on_2x2(topo):
+    """The four-chip WordCount exchange with ``read.mergeImpl=pallas``:
+    the map-side combine, the ragged all-to-all of 3-word rows, the
+    named segment-reduce kernel, and a last output of three int32 words
+    a shard (``reader.combine_status``: flag, rows received, rows out)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sparkucx_tpu.config import TpuShuffleConf
+    from sparkucx_tpu.shuffle.plan import make_plan
+    from sparkucx_tpu.shuffle.reader import _build_step_uncached
+    n = 4
+    plan = dataclasses.replace(
+        make_plan(np.full(n, 4096), n, 256, TpuShuffleConf(use_env=False)),
+        impl="native", combine="sum", combine_words=WORDCOUNT_WIDTH - 2,
+        combine_dtype="<i4", kernel_impl="pallas", pallas_interpret=False)
+    mesh = Mesh(np.array(topo.devices), ("shuffle",))
+    sh = NamedSharding(mesh, P("shuffle"))
+    step = _build_step_uncached(mesh, "shuffle", plan, WORDCOUNT_WIDTH)
+    lowered = step.lower(
+        jax.ShapeDtypeStruct((n * plan.cap_in, WORDCOUNT_WIDTH), jnp.int32,
+                             sharding=sh),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sh))
+    last = lowered.out_info[3]
+    assert (last.shape, last.dtype) == ((3 * n,), jnp.int32)
+    text = lowered.compile().as_text()
+    assert "ragged-all-to-all" in text
+    assert "%segreduce_blocked" in text
 
 
 def test_wordcount_combine_step_compiles_carried(topo):

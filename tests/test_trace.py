@@ -223,7 +223,8 @@ def test_clear_resets():
 @pytest.fixture()
 def annotations(monkeypatch):
     """Spy on ``jax.profiler.TraceAnnotation``: every annotation the
-    tracer builds, as [name, attrs, entered, exited]."""
+    tracer builds, as [name, attrs, entered, exited], then the attrs of
+    each later ``set_metadata``."""
     import jax.profiler
     from sparkucx_tpu.utils import trace as trace_mod
     seen = []
@@ -242,6 +243,10 @@ def annotations(monkeypatch):
         def __exit__(self, *exc):
             self._rec[3] += 1
             return super().__exit__(*exc)
+
+        def set_metadata(self, **attrs):
+            self._rec.append(attrs)
+            return super().set_metadata(**attrs)
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
     monkeypatch.setattr(trace_mod, "_ANNOTATION", None)   # rebuilt on Spy
@@ -264,10 +269,13 @@ def test_every_span_enters_one_annotation(annotations, enabled):
 
 
 def test_disabled_span_set_is_noop(annotations):
+    """Nothing reaches the ring; the attrs go to the annotation's
+    stats."""
     t = Tracer(enabled=False)
     with t.span("shuffle.fetch", partition=2) as sp:
         assert sp.set(bytes=10) is sp
-    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1]]
+    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1,
+                            {"bytes": 10}]]
     assert t.spans() == []
 
 
@@ -278,8 +286,9 @@ def test_enabled_span_set_updates_ring_only(annotations):
     (span,) = t.spans()
     assert span.attrs == {"partition": 2, "bytes": 10}
     assert span.depth == 0 and span.dur_us >= 0
-    # the annotation took its stats at entry
-    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1]]
+    # the annotation took its stats at entry, and set()'s while open
+    assert annotations == [["shuffle.fetch", {"partition": 2}, 1, 1,
+                            {"bytes": 10}]]
 
 
 def test_spans_land_in_a_profiler_capture(tmp_path):
@@ -294,6 +303,8 @@ def test_spans_land_in_a_profiler_capture(tmp_path):
         with t.span("shuffle.read.test"):
             with t.span("shuffle.d2h", bytes=4096, what="seg"):
                 pass
+            with t.span("shuffle.merge", impl="host") as sp:
+                sp.set(rows_in=7, rows_out=3)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
@@ -304,6 +315,8 @@ def test_spans_land_in_a_profiler_capture(tmp_path):
               for line in plane.lines for ev in line.events}
     assert events["shuffle.read.test"] == {}
     assert events["shuffle.d2h"] == {"bytes": 4096, "what": "seg"}
+    assert events["shuffle.merge"] == {"impl": "host", "rows_in": 7,
+                                       "rows_out": 3}
 
 
 def test_shuffle_read_emits_spans(manager_factory):
