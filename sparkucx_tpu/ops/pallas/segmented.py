@@ -399,7 +399,11 @@ def _segreduce_blocked_kernel(x_ref, o_ref, n_ref, state_ref, acc_ref,
     slot and stamps n_out.  Every store is a whole-block write, and the
     output is gridded like the input, so VMEM holds a few small blocks
     whatever the capacity; the wrapper compacts the per-tile heads into
-    one dense prefix.
+    one dense prefix by one gather, its index built with no search
+    (``_compact_src``: each block's step to the next block's offset
+    scattered to its dense end, then a prefix sum; the offset rises
+    with the block because a tile emits fewer rows than its block
+    holds).
 
     Rows travel TRANSPOSED through HBM — ``x_ref`` is a [S_in, tile]
     block of (row lanes + partition) x rows, ``o_ref`` a [S_out, 2 tile]
@@ -615,17 +619,39 @@ def _segreduce_pallas(rows, part, num_parts: int, sum_words: int,
         **kw,
     )(xt)
     # compact the per-tile heads (each block's live rows are a prefix)
-    # into one dense prefix of ``cap`` rows by one gather
+    # into one dense prefix of ``cap`` rows by one gather; its index is
+    # a prefix sum of per-block steps scattered to the blocks' dense
+    # ends, no search: the block offset it builds rises strictly, as a
+    # tile emits fewer than ``blk`` rows
     part2 = out_t[width]
     counts = (part2.reshape(nt, blk) < num_parts).sum(
         axis=1, dtype=jnp.int32)
-    cum = jnp.cumsum(counts)
-    j = jnp.arange(cap, dtype=jnp.int32)
-    b = jnp.minimum(jnp.searchsorted(cum, j, side="right"),
-                    nt - 1).astype(jnp.int32)
-    src = jnp.clip(b * blk + j - (cum - counts)[b], 0, nt * blk - 1)
+    src = _compact_src(counts, cap, blk)
     out = jnp.take(out_t[:width + 1], src, axis=1).T     # [cap, width + 1]
     return out[:, :width], out[:, width:], n
+
+
+def _compact_src(counts: jnp.ndarray, cap: int, blk: int) -> jnp.ndarray:
+    """Source column in the kernel's gridded output of each of ``cap``
+    dense slots: tile b's ``counts[b]`` live rows start at column
+    ``b * blk`` and land at dense slots from ``start[b]`` (the exclusive
+    prefix sum), so slot j of block b reads ``j + off[b]`` with
+    ``off[b] = b * blk - start[b]``, the sum of ``blk - counts[b']``
+    over the blocks before b.  Each block adds that step at its dense
+    end ``cum[b]``; ``cum`` never falls, so the running sum at slot j
+    holds the steps of exactly the blocks before j's own.  One
+    scatter-add of ``nt`` steps (empty blocks share an end with a
+    neighbour and their steps add up; an end at ``cap`` drops) and one
+    prefix sum, where a search of every slot is a loop of cap-length
+    gathers.  ``off`` rises strictly with b (a tile emits at most
+    ``tile + 1 < blk`` rows), so live slots read distinct columns.
+    Slots past the live total read garbage that ``_mask_past_n``
+    clears."""
+    nt = counts.shape[0]
+    step = jnp.zeros((cap,), jnp.int32).at[jnp.cumsum(counts)].add(
+        blk - counts, mode="drop")
+    src = jnp.cumsum(step) + jnp.arange(cap, dtype=jnp.int32)
+    return jnp.minimum(src, nt * blk - 1)
 
 
 def _mask_past_n(rows_out, part2, n, num_parts: int):

@@ -100,6 +100,27 @@ def test_segment_reduce_kernel_compiles(one_chip, caps):
     assert "%segreduce_blocked" in text
 
 
+def test_segment_reduce_compaction_index_has_no_search(one_chip, caps):
+    """The segment-reduce's compaction index at the four-chip wordcount
+    wave's capacity and tile count compiles to no loop and no gather: a
+    binary search of every output slot is a ``while`` of cap-length
+    gathers on the chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from sparkucx_tpu.ops.pallas.segmented import _TILE, _compact_src
+    cap = caps[1].cap_out
+    counts = jax.ShapeDtypeStruct((-(-cap // _TILE),), jnp.int32,
+                                  sharding=one_chip)
+    text = _compiled_text(lambda c: _compact_src(c, cap, 2 * _TILE),
+                          counts)
+    assert re.search(r"\sscatter\(", text)
+    assert not re.search(r"\swhile\(", text)
+    assert not re.search(r"\sgather\(", text)
+
+
 def test_fused_int8_reduce_kernel_compiles(one_chip, caps):
     """The int8-wire fused dequant segment-reduce over terasort-width
     float rows in wire format."""

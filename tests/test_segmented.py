@@ -470,6 +470,38 @@ def test_blocked_merge_reduce_parity_fuzz(split):
     assert np.array_equal(np.asarray(jr)[:k], np.asarray(pr)[:k])
 
 
+# per-tile live counts of the blocked kernel's gridded output (tile 128,
+# block 256) and the dense capacity they compact into
+_COMPACT_CASES = {
+    "all_empty": ([0] * 8, 1000),
+    "all_full_flush_at_cap": ([128] * 7 + [129], 7 * 128 + 129),
+    "empty_runs_between_live": ([5, 0, 0, 0, 17, 0, 128, 0, 0, 3], 200),
+    "single_live_first": ([40, 0, 0, 0, 0], 640),
+    "single_live_last": ([0, 0, 0, 0, 129], 640),
+    "ends_at_cap": ([3, 0, 60, 1], 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMPACT_CASES))
+def test_compact_src_matches_searchsorted_oracle(case):
+    """The compaction index (one scatter-add and a prefix sum) reads, for
+    every live dense slot, the column a binary search of the slot over
+    the counts' prefix sums names."""
+    import jax.numpy as jnp
+    counts, cap = _COMPACT_CASES[case]
+    counts = np.asarray(counts, np.int64)
+    nt, blk = counts.size, 2 * S._TILE
+    cum = np.cumsum(counts)
+    j = np.arange(cap)
+    b = np.minimum(np.searchsorted(cum, j, side="right"), nt - 1)
+    want = np.clip(b * blk + j - (cum - counts)[b], 0, nt * blk - 1)
+    got = np.asarray(S._compact_src(jnp.asarray(counts, jnp.int32), cap,
+                                    blk))
+    live = int(cum[-1])
+    assert got.shape == (cap,)
+    assert np.array_equal(got[:live], want[:live])
+
+
 def test_gate_helper_is_the_single_skip_authority():
     """The sweep's skip reason IS kernel_gate_reason's string: on a
     gated backend every parity test above skips with it verbatim, and
