@@ -41,6 +41,8 @@ import time
 
 import numpy as np
 
+from benchmark.yardstick import keystream, payload_lanes, slices, zipf_words
+
 # HiBench conf/workloads/micro/terasort.conf profiles, records per chip
 PROFILES = {"large": 32_000_000, "small": 3_200_000}
 VALUE_LANES = 23                    # 92 B payload: 10 B key + 90 B value
@@ -48,36 +50,10 @@ NUM_MAPS = 16
 PARTITIONS_PER_CHIP = 64
 WORDS = 1 << 20
 ZIPF_S = 1.1
-PAYLOAD_STREAM = 1 << 48            # payload index stream, disjoint from keys
 # 3.2 GB a chip fits one exchange on one chip, not on four: there the
 # receive buffer carries a2a.capacityFactor headroom, so the exchange is
 # streamed in this many waves (a2a.waveRows = rows per chip / WAVES)
 WAVES_MULTICHIP = 8
-
-
-def _keystream(seed: int, start: int, n: int) -> np.ndarray:
-    from sparkucx_tpu.workloads.terasort import keystream
-    return keystream(seed, start, n)
-
-
-def _payload(seed: int, start: int, n: int) -> np.ndarray:
-    """[n, 23] int32 payload lanes of rows [start, start+n): splitmix64
-    of a second index stream, 12 words per row viewed as 24 lanes."""
-    words = _keystream(seed, PAYLOAD_STREAM + 12 * start, 12 * n)
-    return words.view(np.int32).reshape(n, 24)[:, :VALUE_LANES].copy()
-
-
-def _zipf_words(seed: int, n: int) -> np.ndarray:
-    rank = np.arange(1, WORDS + 1, dtype=np.float64)
-    cdf = np.cumsum(rank ** -ZIPF_S)
-    cdf /= cdf[-1]
-    u = np.random.default_rng(seed).random(n)
-    return np.minimum(np.searchsorted(cdf, u), WORDS - 1).astype(np.int64)
-
-
-def _slices(total: int, parts: int):
-    edges = [total * i // parts for i in range(parts + 1)]
-    return list(zip(edges[:-1], edges[1:]))
 
 
 def _device_line(jax) -> dict:
@@ -214,9 +190,9 @@ def run_terasort(svc, rows, seed, chips, jax, profile):
     sampler = ReservoirSampler(capacity=max(4096, 128 * nparts), seed=seed)
     maps = []
     digest = 0
-    for a, b in _slices(rows, NUM_MAPS):
-        keys = _keystream(seed, a, b - a)
-        vals = _payload(seed, a, b - a)
+    for a, b in slices(rows, NUM_MAPS):
+        keys = keystream(seed, a, b - a)
+        vals = payload_lanes(seed, a, b - a, VALUE_LANES)
         sampler.add(keys)
         digest = (digest + digest_sum(keys, vals)) % (1 << 64)
         maps.append((keys, vals))
@@ -244,13 +220,13 @@ def run_terasort(svc, rows, seed, chips, jax, profile):
 
 def run_wordcount(svc, rows, seed, chips, jax, profile):
     nparts = PARTITIONS_PER_CHIP * chips
-    words = _zipf_words(seed, rows)
+    words = zipf_words(seed, rows, WORDS, ZIPF_S)
     want = np.bincount(words, minlength=WORDS).astype(np.int64)
     ones = np.ones((rows, 1), np.int32)
     sid = 2
     h = svc.register_shuffle(sid, NUM_MAPS, nparts)
     t0 = time.perf_counter()
-    for m, (a, b) in enumerate(_slices(rows, NUM_MAPS)):
+    for m, (a, b) in enumerate(slices(rows, NUM_MAPS)):
         svc.write(h, m, words[a:b], ones[a:b])
     write_s = time.perf_counter() - t0
     walls = []

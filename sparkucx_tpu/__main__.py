@@ -66,7 +66,7 @@ Subcommands:
     fast|slow`` exits 3 on a burn of that speed — the CI gate shape.
 
 ``kernelbench [--reps N] [--rows-log2 K] [--out PATH]``
-    The blocked-kernel microbench (ops/pallas/microbench.py): jnp
+    The blocked-kernel microbench (``ops.pallas.microbench``): jnp
     oracle timed on every backend, the pallas arm timed only where the
     kernels compile natively (TPU) and recorded as an explicit skip
     with the capability-gate reason elsewhere, parity graded wherever
@@ -805,7 +805,7 @@ def main(argv=None) -> int:
                             "this grade or worse fired (CI gate)")
     p_kb = sub.add_parser(
         "kernelbench",
-        help="blocked-kernel microbench (ops/pallas/microbench.py): "
+        help="blocked-kernel microbench (ops.pallas.microbench): "
              "jnp oracle timed everywhere, pallas timed where it "
              "compiles natively (TPU) and recorded as a skip with the "
              "gate reason elsewhere, parity graded wherever the "
@@ -817,8 +817,7 @@ def main(argv=None) -> int:
                       help="log2 rows for the bulk cases (default 13)")
     p_kb.add_argument("--out", default=None,
                       help="also write the artifact JSON here "
-                           "(atomic; e.g. bench_runs/tpu_kernels.json "
-                           "on a TPU run)")
+                           "(atomic)")
     args = ap.parse_args(argv)
     if args.cmd == "kernelbench":
         return _cmd_kernelbench(args)

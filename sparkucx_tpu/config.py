@@ -866,8 +866,7 @@ class TpuShuffleConf:
         transport posture); ``staged`` (default) — commit publishes
         per-map checksum records beside the size rows and the read path
         re-verifies the staged/spill bytes at pack time, before they
-        enter the exchange (memory-bandwidth fold64, <3% of exchange
-        wall — bench --stage integrity gates it); ``full`` — staged
+        enter the exchange (memory-bandwidth fold64); ``full`` — staged
         plus a post-collective check of the host-drained rows per
         reduce partition against order-independent row-digest sums
         (bit-equivalent for raw/lossless wires; the int8 tier verifies

@@ -473,7 +473,7 @@ def make_device_dispatch_step(mesh: Mesh, cfg: MoEConfig, cap: int,
 def host_staged_consume(result, step, params, mesh: Mesh, cap: int,
                         width: int, axis: str = "shuffle"):
     """The legacy round-trip the device sink deletes, as one callable —
-    the A/B arm of ``bench --stage devread`` and the doctor's
+    the host arm of the device-sink tests and the doctor's
     ``host_roundtrip`` evidence source: drain every partition of a
     HOST-sink result to numpy (D2H — counted by the reader into
     ``shuffle.read.d2h.bytes``), re-pack the rows, re-upload them to the

@@ -30,8 +30,7 @@ pass compiles ZERO — the same programs/hits counters the exchange
 stepcache gates ride (``compile.step.programs``).
 
 ``python -m sparkucx_tpu kernelbench`` prints the artifact as one JSON
-doc; ``bench.py --stage tpu`` embeds the same artifact in the
-``bench_runs/tpu_*`` namespace.
+doc.
 """
 
 from __future__ import annotations

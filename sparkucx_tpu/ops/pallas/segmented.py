@@ -10,7 +10,7 @@ so the fold over wave buffers happens where the buffers already live.
 
 Two primitives, each with a jnp/XLA path (the bit-exact oracle on every
 backend) and a BLOCKED pallas kernel in the ``ops/pallas`` lineage
-(``ragged_a2a.py`` discipline: gate predicates tests/bench consult,
+(``ragged_a2a.py`` discipline: gate predicates tests consult,
 interpret resolution from the backend at trace time):
 
 * :func:`merge_rows` — merge TWO partition-major key-sorted row buffers

@@ -168,7 +168,7 @@ def destination_sort(
         # destination key is the next width lever.
         # Valid only when every key value (incl. the padding sentinel
         # num_dests) fits int8; conf-selectable for on-chip A/B
-        # (bench --sort-impl multisort8).
+        # (a2a.sortImpl=multisort8).
         narrow = _int8_key_ok(num_dests) and rows.ndim == 2
         method = "multisort" if narrow else "argsort"
     else:

@@ -4,7 +4,7 @@ The exchange steps cost minutes of XLA compile per fresh process, so
 ``service.connect()`` + ``warmup()`` would re-pay that on every restart
 without a persistent cache. This module is wired into
 :class:`~sparkucx_tpu.runtime.node.TpuNode` init (and therefore every
-``connect()``), with bench.py delegating to the SAME path:
+``connect()``, the benchmark's set-up included):
 
     spark.shuffle.tpu.compile.cacheEnabled        master switch (default on)
     spark.shuffle.tpu.compile.minCompileTimeSecs  persistence threshold
@@ -65,7 +65,7 @@ def configure_compile_cache(conf) -> Optional[str]:
                  path, conf.compile_min_compile_time_secs)
         return path
     except Exception as e:
-        # never let cache plumbing cost a shuffle (or a bench window)
+        # never let cache plumbing cost a shuffle
         log.warning("persistent compile cache unavailable (%s); "
                     "compiles will not persist", e)
         return None

@@ -81,7 +81,7 @@ class DeviceMonitor:
     """Daemon-thread device-memory sampler (see module docstring).
 
     Publishes into ``node.metrics`` gauges; keeps a bounded ring of raw
-    samples (``samples()``) for tests and the bench's devplane artifact.
+    samples (``samples()``) for tests.
     Sampling never raises into anything: every probe is guarded, and a
     backend without ``memory_stats`` simply yields null device fields.
     """

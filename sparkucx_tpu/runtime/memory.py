@@ -95,7 +95,7 @@ class HostMemoryPool:
         # bytes). retain/release refcounts deliberately do not move it:
         # the gauge answers "how much pinned staging is checked out",
         # which is the get/put discipline — the number the wave pipeline's
-        # bounded-footprint claim is graded on (bench --stage pipeline).
+        # bounded-footprint claim is graded on.
         self._bytes_lock = threading.Lock()
         self._in_use_bytes = 0
         self._peak_bytes = 0
@@ -198,9 +198,9 @@ class HostMemoryPool:
 
     def reset_peak_bytes(self) -> int:
         """Reset the byte high-watermark to the current in-use level and
-        return the PRIOR peak — the measure-a-window primitive the
-        pipeline bench uses to attribute peak pinned bytes to one A/B
-        arm instead of whichever arm ran first."""
+        return the PRIOR peak — the measure-a-window primitive that
+        attributes peak pinned bytes to one read instead of whichever
+        ran first."""
         with self._bytes_lock:
             prior = self._peak_bytes
             self._peak_bytes = self._in_use_bytes

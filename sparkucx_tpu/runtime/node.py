@@ -336,7 +336,7 @@ class TpuNode:
         """THE canonical live-snapshot shape for this process: both
         registries (process-global + node), the tracer, the arena
         watermark and the process identity — one seam so the facades,
-        the CLI's live mode, the bench's doctor pass and the cluster
+        the CLI's live mode and the cluster
         harness cannot drift on which fields a doctor rule can rely on.
         ``reports`` is the manager's exchange-report list when the
         caller owns a manager (the node itself does not).
@@ -407,8 +407,7 @@ class TpuNode:
         gauges + anchor only. The full telemetry_snapshot additionally
         summarizes spans and serializes chrome events — per-scrape
         costs a per-window delta never reads, and the roll rides the
-        read path's cadence budget (bench --stage slo gates the whole
-        plane < 1% of the exchange loop)."""
+        read path's cadence budget."""
         from sparkucx_tpu.utils.export import collect_snapshot
         from sparkucx_tpu.utils.metrics import GLOBAL_METRICS
         self.publish_pool_gauges()

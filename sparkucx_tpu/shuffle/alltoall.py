@@ -155,8 +155,8 @@ def validate_topology(topology: str,
                       conf_key: str = A2A_TOPOLOGY_KEY) -> str:
     """The one validation seam for the exchange-topology set (the
     validate_impl/validate_wire/validate_sink discipline): config.py,
-    the topology resolver (shuffle/topology.resolve_topology) and the
-    bench CLI accept exactly ``ALLOWED_TOPOLOGIES``."""
+    and the topology resolver (shuffle/topology.resolve_topology) accept
+    exactly ``ALLOWED_TOPOLOGIES``."""
     if topology not in ALLOWED_TOPOLOGIES:
         raise ValueError(
             f"{conf_key}={topology!r}: want one of {ALLOWED_TOPOLOGIES} "
@@ -181,8 +181,8 @@ def validate_merge_impl(impl: str,
 
 
 def validate_sink(sink: str, conf_key: str = READ_SINK_KEY) -> str:
-    """The one validation seam for the read-sink tier set: config.py,
-    the manager's per-read resolve and the bench CLI accept exactly
+    """The one validation seam for the read-sink tier set: config.py
+    and the manager's per-read resolve accept exactly
     ``ALLOWED_SINKS`` (the validate_impl/validate_wire discipline)."""
     if sink not in ALLOWED_SINKS:
         raise ValueError(
@@ -200,7 +200,7 @@ WIRE_SEED_STREAMS = 4
 
 def validate_wire(wire: str, conf_key: str = A2A_WIRE_KEY) -> str:
     """The one validation seam for the wire-compression tier set:
-    config.py and the bench CLI accept exactly ``ALLOWED_WIRES``, and the
+    config.py accepts exactly ``ALLOWED_WIRES``, and the
     error names the conf key to turn (the validate_impl discipline)."""
     if wire not in ALLOWED_WIRES:
         raise ValueError(
@@ -280,8 +280,8 @@ def wire_unpack_rows(rows: jnp.ndarray, width: int,
 
 
 def validate_impl(impl: str, conf_key: str = A2A_IMPL_KEY) -> str:
-    """The one validation seam for the a2a implementation set: config.py,
-    select_impl and the bench CLI all accept exactly ``ALLOWED_IMPLS``,
+    """The one validation seam for the a2a implementation set: config.py
+    and select_impl both accept exactly ``ALLOWED_IMPLS``,
     and the error names the conf key to turn."""
     if impl not in ALLOWED_IMPLS:
         raise ValueError(
@@ -604,7 +604,7 @@ def ragged_shuffle(data: jnp.ndarray, local_sizes: jnp.ndarray, axis_name: str,
     if impl == "auto" and local_sizes.shape[0] == 1:
         # one shard on this axis — no peer exists; 'auto' means "best
         # transport", so take the local move (see _a2a_local). An EXPLICIT
-        # impl is honored verbatim: the bench/TPU-test lowering proofs
+        # impl is honored verbatim: the TPU-test lowering proofs
         # pass impl='native' precisely to exercise the real collective.
         return _a2a_local(data, local_sizes, axis_name, out_capacity)
     impl = select_impl(impl)

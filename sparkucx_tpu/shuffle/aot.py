@@ -309,7 +309,7 @@ def aot_compile_strip_step(
     """Compile the single-shard STRIP-sorted plain step (a2a.sortStrips,
     reader.step_body fast path) against one chip of an unattached TPU
     topology — proof the batched-strip sort program lowers for the chip
-    at the full bench shape without a chip.
+    at full size without a chip.
 
     The load-bearing bits: the program compiles, carries NO collective
     (n=1 strips path is pure sort — no ragged-all-to-all, no

@@ -212,8 +212,6 @@ class DecisionLedger:
                 # holds 2x the retention target, then compact back to
                 # the newest ``retain`` lines — the on-disk bound is 2x
                 # retain, the rewrite cost amortizes to O(1) per round
-                # (the decisions-stage bench gates this <1% of the
-                # exchange wall)
                 if self._fh is None:
                     self._fh = open(path, "a")
                 self._fh.write(line + "\n")

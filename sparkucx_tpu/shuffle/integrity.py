@@ -51,7 +51,7 @@ lossless wires verify the full rows bit-for-bit-equivalent.
 
 Everything here is host-side numpy: no compiled-program signature grows
 a verification argument, so ``compile.step.programs`` is identical at
-every verify level (the one-program invariant the bench gates).
+every verify level (the one-program invariant).
 """
 
 from __future__ import annotations

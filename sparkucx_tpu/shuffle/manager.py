@@ -216,7 +216,7 @@ class ExchangeReport:
     # as the consumer touches partitions (a lazy result drains after
     # completion, so the figure keeps accruing on the live report); 0 on
     # the device path is the deleted-round-trip evidence the doctor's
-    # host_roundtrip rule and bench --stage devread grade.
+    # host_roundtrip rule grades.
     sink: str = "host"
     d2h_bytes: int = 0
     # Device-kernel tier the combine/ordered fold path RAN
@@ -231,8 +231,7 @@ class ExchangeReport:
     # cross-wave DEVICE merge fold spent (reader.device_merge_fold —
     # compiled merge programs over the completed waves, blocked for an
     # honest figure). 0.0 on host sinks, single-shot device reads (the
-    # exchange step already merged) and plain device reads. The
-    # bench --stage devcombine merge-leg gate reads this.
+    # exchange step already merged) and plain device reads.
     merge_ms: float = 0.0
     # Multi-tenant plane (shuffle/tenancy.py): the tenant this shuffle
     # was registered under (conf tenant.id, or the register_shuffle
@@ -306,8 +305,7 @@ class ExchangeReport:
 
     # public field names, resolved once: to_dict runs per report per
     # doctor/stats/dump pass, and dataclasses.asdict's recursive deepcopy
-    # made it the single hottest piece of a doctor pass (bench --stage
-    # obs-overhead doctor_pass_ms)
+    # made it the single hottest piece of a doctor pass
     _PUBLIC_FIELDS: ClassVar[tuple] = ()
 
     def to_dict(self) -> Dict:
@@ -2346,7 +2344,7 @@ class TpuShuffleManager:
         rep.plan_family = str(plan.family())
         # plain-python arithmetic over the (tiny, per-peer) lists: numpy
         # reductions on 8-element arrays cost more in dispatch than the
-        # math, and this runs on every read (bench --stage obs-overhead)
+        # math, and this runs on every read
         rep.peer_rows = [int(x) for x in nvalid]
         rep.peer_bytes = [r * width * 4 for r in rep.peer_rows]
         rep.rows_global = sum(rep.peer_rows)
@@ -2744,8 +2742,7 @@ class TpuShuffleManager:
         and publish the ``shuffle.phase.ms{phase=...}`` counters that
         ride TelemetryHistory frames into the phase_regression rule.
         Tracer off = one enabled check + one no-op record_span per
-        exchange (the <1% disabled-path discipline, gated by
-        bench --stage anatomy). Fold failures degrade to an un-annotated
+        exchange. Fold failures degrade to an un-annotated
         report — anatomy must never take down a read's settlement."""
         tracer = self.node.tracer
         if not tracer.enabled or not report._t_start:

@@ -176,8 +176,7 @@ class ShufflePlan:
 # Kept as data so a new measurement is a one-line change with a citation.
 _MEASURED_STRIPS: dict = {}
 
-# Valid a2a.sortStrips bounds — ONE constant shared by conf validation
-# and bench's parse-time check so the two cannot drift.
+# Valid a2a.sortStrips bounds, checked by conf validation.
 STRIPS_RANGE = (1, 4096)
 
 # Valid a2a.waveDepth bounds (the STRIPS_RANGE discipline: one constant
@@ -262,9 +261,7 @@ def default_sort_strips(backend: str, num_shards: int) -> int:
 def resolve_sort_strips(conf_val, num_shards: int) -> int:
     """'auto' -> backend-measured default; anything else is already an
     int (conf validation). jax imported lazily: plan.py stays importable
-    without touching a backend. Public: bench.py resolves its
-    --sort-strips flag through this same path so the bench measures
-    exactly what production make_plan would run."""
+    without touching a backend."""
     if conf_val != "auto":
         return int(conf_val)
     import jax
@@ -376,8 +373,7 @@ class RaggedLayout:
     * ``pad_ratio`` — wire/payload: 1.0 means every byte on the wire was a
       real byte; dense at uniform occupancy pays ~P x capacityFactor, and
       skew (which grows cap_out) only inflates it further — the figure
-      ``bench --stage ragged`` sweeps and the doctor's ``padding_waste``
-      rule grades.
+      the doctor's ``padding_waste`` rule grades.
 
     Hierarchical (two-stage ICI/DCN) exchanges ride the same formula per
     stage; the descriptor reports the flat single-collective cost (a lower

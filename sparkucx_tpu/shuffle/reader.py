@@ -65,7 +65,7 @@ def pull_to_host(arr, what: str, res=None, nbytes: Optional[int] = None,
 
     Payload pulls are accounted to the reader result ``res``: the
     cumulative ``shuffle.read.d2h.bytes`` counter (the figure the device
-    sink drives to ZERO — bench --stage devread gates it) plus the
+    sink drives to ZERO) plus the
     owning read's ExchangeReport when the manager armed the callback
     (``_d2h_cb``, set at on_done). Pulls that happen BEFORE arming (the
     distributed force-materialize runs inside result()) park in
@@ -193,9 +193,9 @@ def overflow_flags(last, num_shards: int) -> np.ndarray:
 def step_body(plan: ShufflePlan, axis: str):
     """The per-shard exchange step (call under shard_map over ``axis``).
 
-    Exposed separately from :func:`_build_step` so bench.py measures the
-    EXACT production pipeline (inside its own scan harness) rather than a
-    re-implementation that could drift.
+    Exposed separately from :func:`_build_step` so the AOT chip
+    compiles (shuffle/aot.py) and the tests lower the EXACT production
+    pipeline rather than a re-implementation that could drift.
 
     PARTITION-MAJOR design: the send side sorts by GLOBAL reduce-partition
     id. The blocked partition->device map is monotone, so one sort groups
@@ -216,7 +216,7 @@ def step_body(plan: ShufflePlan, axis: str):
         return _pallas_step_body(plan, axis)
     # numpy, NOT jnp: a closed-over concrete jnp array becomes a lifted
     # executable parameter, which jax's C++ fastpath fails to re-supply on
-    # repeat calls when the step is traced inside a caller's scan (bench);
+    # repeat calls when the step is traced inside a caller's scan;
     # a numpy constant inlines as a literal at trace time
     bounds = _device_bounds(R, Pn)
     part_fn = _make_part_fn(plan, R)
@@ -1552,7 +1552,7 @@ class DeviceShuffleReaderResult:
     this result drops its OWN references to a wave's buffers before the
     handoff, so a consumer jitted with ``donate_argnums`` may alias them
     in place. Zero D2H by construction: ``shuffle.read.d2h.bytes`` does
-    not move (bench --stage devread gates the delta at 0), where the
+    not move (tests/test_device_sink.py holds the delta at 0), where the
     host path pays a full drain plus the consumer's re-upload.
 
     The admission reservation of the exchange (HBM residency — the

@@ -61,7 +61,7 @@ COST_CAPTURE = True
 MEMORY_PROBE = True
 
 # Field surface of one program cost record — fixed so consumers (the
-# ExchangeReport join, bench --stage devplane, dashboards) can rely on
+# ExchangeReport join, dashboards) can rely on
 # key presence even when a backend yields nothing (CPU memory_stats-less
 # paths, older jax): absent data is None, never a missing key.
 _COST_FIELDS = ("backend", "flops", "bytes_accessed", "argument_bytes",

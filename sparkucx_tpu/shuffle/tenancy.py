@@ -410,8 +410,7 @@ class FairShareQueue:
 
 class FifoAdmitQueue:
     """The historical strictly-FIFO deferral order behind the same
-    interface (``tenant.fairShare=false`` — the escape hatch and the
-    bench's contrast arm)."""
+    interface (``tenant.fairShare=false`` — the escape hatch)."""
 
     def __init__(self):
         self._q: deque = deque()                # (ticket, tenant, nbytes)
@@ -497,8 +496,7 @@ class ShuffleFuture:
     """Handle to one async shuffle read — ``done()`` / ``result()`` /
     ``exception()`` / ``add_done_callback()`` over the facade read that
     produced it. ``wall_ms`` (after completion) is the read's execution
-    wall on the worker, EXCLUDING queue wait — the per-exchange figure
-    the tenancy bench's p99 is computed from; ``queued_ms`` is the time
+    wall on the worker, EXCLUDING queue wait; ``queued_ms`` is the time
     it waited for a worker."""
 
     __slots__ = ("_fut", "_times", "tenant", "shuffle_id")

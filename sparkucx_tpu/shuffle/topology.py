@@ -188,8 +188,7 @@ def tier_cross_rows(dev_matrix, topo: TopologyDescriptor) -> Dict[str, int]:
     move iff the device COLUMN changes; stage 2 moves it from (s, d')
     to (s', d') — a real DCN move iff the SLICE changes. Each row
     appears in the DCN count at most once by construction: this is the
-    each-row-crosses-the-slow-tier-exactly-once proof the bench gate
-    reads."""
+    each-row-crosses-the-slow-tier-exactly-once proof."""
     m = np.asarray(dev_matrix, dtype=np.int64)
     D = max(1, topo.per_slice)
     src = np.arange(m.shape[0])

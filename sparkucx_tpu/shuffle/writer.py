@@ -363,9 +363,8 @@ class MapOutputWriter:
             moved = self._staged_bytes
             self._staged_bytes = 0
         if moved:
-            # the spill-proven evidence (bench --stage analytics gates a
-            # positive delta at the scale shape; the doctor's spill_bound
-            # rule carries it) — counted at the ONE seam every spill
+            # the spill-proven evidence (the doctor's spill_bound rule
+            # carries it) — counted at the ONE seam every spill
             # passes through, threshold-triggered and budget-forced alike
             GLOBAL_METRICS.inc(C_SPILL_BYTES, float(moved))
             GLOBAL_METRICS.inc(C_SPILL_COUNT, 1.0)

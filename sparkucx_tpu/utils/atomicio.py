@@ -12,7 +12,6 @@ re-invents a weaker version:
 * ``utils/export.write_snapshot`` (metrics dumps, flight postmortems)
 * spill sidecars + the per-shuffle commit manifest (shuffle/writer.py,
   shuffle/durable.py)
-* every ``bench.py`` artifact (the CI regress baselines diff them)
 * the CLI's timeline/stats outputs (``__main__.py``)
 
 The persistent XLA compile cache is jax-managed and already writes

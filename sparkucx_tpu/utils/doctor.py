@@ -129,9 +129,6 @@ slow_proposer     one process is consistently the last     spark.shuffle.tpu.fai
                   audited rounds, min ms lag, dominance
                   share
 ================  =======================================  =====================================
-
-The same :class:`Finding` schema carries ``bench.py --stage regress``
-output, so perf regressions and runtime anomalies read identically.
 """
 
 from __future__ import annotations

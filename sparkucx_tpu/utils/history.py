@@ -31,7 +31,7 @@ Cadence: NO new sampling thread. Rolling is driven off the
 :class:`~sparkucx_tpu.utils.export.PeriodicDumper` tick (service.py
 starts one whenever history or a dump dir is configured); ``tick()``
 closes a window only once ``history.windowSecs`` elapsed, and
-``roll()`` force-closes one (tests, the bench drill).
+``roll()`` force-closes one (tests).
 
 Conf surface (all under ``spark.shuffle.tpu.``)::
 
@@ -159,9 +159,9 @@ class TelemetryHistory:
         return None
 
     def roll(self, now: Optional[float] = None) -> Optional[Dict]:
-        """Force-close the current window into one frame (tests and the
-        bench burn drill call this to make window boundaries
-        deterministic; production rides :meth:`tick`)."""
+        """Force-close the current window into one frame (tests call
+        this to make window boundaries deterministic; production rides
+        :meth:`tick`)."""
         now = time.time() if now is None else float(now)
         doc = self._collect()
         with self._lock:

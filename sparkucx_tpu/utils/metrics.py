@@ -24,9 +24,8 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 
-# Compile-cost observability (shuffle/stepcache.py, bench --stage
-# coldstart): ONE place for the counter names so the cache, the bench and
-# the tests cannot drift on spelling.
+# Compile-cost observability (shuffle/stepcache.py): ONE place for the
+# counter names so the cache and the tests cannot drift on spelling.
 COMPILE_PROGRAMS = "compile.step.programs"   # distinct step programs built
 COMPILE_HITS = "compile.step.hits"           # step-cache lookups served
 COMPILE_SECONDS = "compile.step.seconds"     # first-invocation wall secs
@@ -124,7 +123,7 @@ C_INTEGRITY_RECOVERED = "shuffle.integrity.recovered.count"
 
 # Device-resident read plane (read.sink, shuffle/reader.py): ONE place
 # for the names so the reader's drain paths, the MoE host-staged
-# consumer, the doctor's host_roundtrip rule, and bench --stage devread
+# consumer and the doctor's host_roundtrip rule
 # cannot drift. C_D2H counts PAYLOAD bytes pulled device-to-host by a
 # reader result (whole-shard drains, per-partition device slices, the
 # distributed force-materialize) — metadata (seg matrices) is excluded;
@@ -181,7 +180,7 @@ C_SUBMIT_THROTTLED = "shuffle.submit.throttled.count"
 # point-in-time admission reservation per tenant (set-semantics gauge)
 G_TENANT_INFLIGHT = "shuffle.inflight.bytes"
 
-# External-memory analytics plane (workloads/, bench --stage analytics):
+# External-memory analytics plane (workloads/):
 # ONE place for the names so the pipelines, the doctor's spill_bound
 # rule and the tests cannot drift. C_SPILL_BYTES accumulates bytes the
 # map writers moved from the pinned arena to sealed spill files
@@ -603,9 +602,7 @@ class Metrics:
         reads are GIL-atomic (histogram entries are never deleted, only
         added under the lock), so the registry lock is skipped and the
         cost is one dict lookup + the histogram's own update. This is
-        the common case on the read hot path and the reason the
-        disabled-telemetry overhead stays <1% (bench --stage
-        obs-overhead)."""
+        the common case on the read hot path."""
         h = self._histograms.get(name)
         if h is not None and not self._reporters:
             h.observe(value)

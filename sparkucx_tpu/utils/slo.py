@@ -22,7 +22,7 @@ burn of 1.0 spends budget exactly as provisioned; the classic
 fast/slow pair (defaults 14.4x over 5 minutes, 6x over 1 hour) is the
 page-now vs ticket-later split. The error budget itself accrues over
 the retained frames — as bad windows age out of retention the budget
-re-accrues, which is what the bench's burn drill watches.
+re-accrues (tests/test_slo.py's burn-and-clear test holds it).
 
 Per-tenant objectives (``tenant.<id>.slo.*``) ride the PR-11 labeled
 series (``shuffle.read.wait_ms{tenant=...}`` etc.), so a whale burning

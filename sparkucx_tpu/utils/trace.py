@@ -339,8 +339,7 @@ class Tracer:
 def _jsonable(v):
     # fast path: span attrs are overwhelmingly primitives, and a doctor/
     # snapshot pass renders every buffered span — a json.dumps probe per
-    # attr dominated chrome_events() (bench --stage obs-overhead
-    # doctor_pass_ms)
+    # attr dominated chrome_events()
     if v is None or type(v) in (str, int, float, bool):
         return v
     try:

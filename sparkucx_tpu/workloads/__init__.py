@@ -12,7 +12,8 @@ exchanges bounding the pinned pack footprint, sealed sorted runs merged
 k-way off disk), with rows/s as a first-class contract — per-phase
 walls on a :class:`WorkloadReport`, ``workload.rows`` /
 ``workload.phase.ms`` counters feeding the doctor's ``spill_bound``
-rule, and ``bench.py --stage analytics`` gating the whole suite.
+rule, and ``tests/test_workloads.py`` holding each pipeline to its
+oracle under a spilling budget.
 
 The scale model: ``budget_bytes`` bounds the PINNED HOST POOL (the
 staging arena every writer and pack buffer rides —
@@ -23,8 +24,8 @@ pool-watermark force-spill valve), waves keep the read side under it.
 
 ``WORKLOADS`` is the name→runner registry behind
 ``python -m sparkucx_tpu workload <name> [--scale] [--budget-mb]``;
-:func:`run_workload` owns the node/manager lifecycle for that CLI (and
-for bench), deriving spill/wave conf from the budget.
+:func:`run_workload` owns the node/manager lifecycle for that CLI,
+deriving spill/wave conf from the budget.
 """
 
 from __future__ import annotations
@@ -282,7 +283,7 @@ def run_workload(name: str, *, budget_mb: float = 16.0,
     workload <name>``). ``scale`` multiplies the ≥10×-budget default
     dataset; conf is derived from the budget
     (:func:`workload_conf_overrides`) with ``conf_overrides`` layered
-    on top (CLI/bench pin ``a2a.impl`` there). Conf keys
+    on top (the CLI's ``--conf`` pins, e.g. ``a2a.impl``). Conf keys
     ``workload.budgetMb`` / ``workload.scale`` in the overrides take
     the same role for conf-driven callers."""
     from sparkucx_tpu.config import TpuShuffleConf

@@ -201,7 +201,7 @@ def _best_warm_report(mgr, base_sid, mode, warm=3):
     """Run ``warm`` exchanges and return the best-attributed of the
     post-cold ones: the conservation bar tests INSTRUMENTATION
     coverage, and a single OS descheduling blip inside one wall must
-    not flake the suite (the bench gate measures the steady state)."""
+    not flake the suite."""
     reps = [_run_exchange(mgr, base_sid + i, mode) for i in range(warm)]
     return max(reps[1:], key=lambda r: -r.dark_ms / r.anatomy_wall_ms
                if r.anatomy_wall_ms else -1e9)

@@ -303,6 +303,30 @@ def test_audit_clean_fleet_quiet():
         assert audit_round(row) is None, row
 
 
+
+def test_agree_rounds_audit_clean_against_own_ledger(ledger_seam):
+    """Real agree() rounds under every contract (unanimity, min and sum
+    reductions, a strict min) settle ok into the ledger, and a two-peer
+    view of that ledger (what an honest fleet's aligned ledgers look
+    like) audits clean through the topic, winner and proposal checks."""
+    reset_epoch(0)
+    rounds = 5
+    for i in range(rounds):
+        agree("t.rows", np.array([256], dtype=np.int64),
+              conf_key="spark.shuffle.tpu.a2a.waveRows")
+        agree("t.depth", np.array([i % 5], dtype=np.int64), reduce="min",
+              conf_key="spark.shuffle.tpu.tenant.asyncAgreedOrder")
+        agree("t.cross", np.array([i * 3], dtype=np.int64), reduce="sum",
+              conf_key="spark.shuffle.tpu.topology")
+        agree("t.capms", np.array([250], dtype=np.int64), reduce="min",
+              audit="strict",
+              conf_key="spark.shuffle.tpu.a2a.capacityFactor")
+    led = ledger_seam.tail()
+    assert len(led) == 4 * rounds and all(r["ok"] for r in led)
+    rows = align_rounds({0: led, 1: led})
+    assert len(rows) == 4 * rounds
+    assert [audit_round(row) for row in rows] == [None] * (4 * rounds)
+
 def test_audit_detects_silent_strict_split():
     """THE case the auditor exists for: a strict min-reduce settles
     green while the peers' conf-derived proposals differ — flagged as

@@ -281,6 +281,51 @@ def test_host_path_reports_d2h_bytes(managers):
     m.unregister_shuffle(h.shuffle_id)
 
 
+
+@pytest.mark.parametrize("sink", ["device", "host"])
+def test_waved_combine_lands_merged(managers, sink):
+    """A waved combine="sum" read folds its per-wave runs into one
+    merged result under either sink: the sums equal the oracle's, the
+    device sink moves no payload to the host before its host view is
+    drained, and the host sink pays the drain. The first read compiles
+    at most the exchange, the fold's zero accumulator and the merge; a
+    warm re-read compiles nothing."""
+    m = managers(**{"a2a.waveRows": "128"})
+    rng = np.random.default_rng(12)
+    keys = [rng.integers(0, 128, size=512) for _ in range(4)]
+    values = [rng.integers(-100, 100, size=(512, 2)).astype(np.int32)
+              for _ in range(4)]
+    h, _ = _stage(m, keys=keys, values=values)
+    want = {}
+    for k, v in zip(np.concatenate(keys).tolist(),
+                    np.concatenate(values).tolist()):
+        acc = want.setdefault(k, [0, 0])
+        acc[0] += v[0]
+        acc[1] += v[1]
+    d0 = GLOBAL_METRICS.get(C_D2H)
+    p0 = GLOBAL_METRICS.get(COMPILE_PROGRAMS)
+    res = m.read(h, combine="sum", sink=sink)
+    assert GLOBAL_METRICS.get(COMPILE_PROGRAMS) - p0 <= 3
+    rep = m.report(h.shuffle_id)
+    assert rep.sink == sink and rep.waves >= 2
+    if sink == "device":
+        assert GLOBAL_METRICS.get(C_D2H) - d0 == 0
+        assert rep.d2h_bytes == 0 and rep.merge_ms > 0.0
+        res = res.host_view()
+    got = {}
+    for _r, (ks, vs) in res.partitions():
+        assert ks.shape[0] == np.unique(ks).shape[0]
+        got.update(zip(ks.tolist(), vs.tolist()))
+    assert got == want
+    if sink == "host":
+        assert rep.d2h_bytes > 0
+    p1 = GLOBAL_METRICS.get(COMPILE_PROGRAMS)
+    warm = m.read(h, combine="sum", sink=sink)
+    assert GLOBAL_METRICS.get(COMPILE_PROGRAMS) - p1 == 0
+    if sink == "device":
+        warm.close()
+    m.unregister_shuffle(h.shuffle_id)
+
 # -- the lazy-materialization race (satellite 1) ----------------------------
 def test_lazy_result_concurrent_first_touch_race(managers):
     """Concurrent first-touch of ONE shared lazy result — a pack-executor

@@ -421,27 +421,6 @@ def test_memory_probe_gated_on_persistent_cache(service_factory):
         GLOBAL_STEP_CACHE.clear()
 
 
-def test_devplane_bench_stage_small(mesh8):
-    """The devplane stage's measurement core at a tiny shape (the full
-    artifact belongs to bench --stage devplane)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    rec = bench.devplane_measure(exchanges=4, rows_per_map=256, maps=2,
-                                 partitions=4, val_words=2)
-    assert rec["disabled_path"] == {"devmon_null_object": True,
-                                    "live_server_off": True,
-                                    "watcher_off": True}
-    assert rec["cost_capture"]["record_on_every_report"] is True
-    assert rec["cost_capture"]["fields_present"] is True
-    # first read compiles; a second may recompile under the learned cap
-    # hint — both stay out of the steady-state bw histogram by design
-    assert rec["bw"]["count"] >= rec["exchanges"] - 2
-    assert rec["bw"]["max_gbps"] > 0
-
-
 def test_watcher_rearms_after_consecutive_healthy_passes(
         service_factory, tmp_path):
     """PR-14 satellite bugfix: a captured finding key that CLEARS for

@@ -5,14 +5,12 @@ each (plus a healthy-cluster fixture asserting ZERO findings — the
 doctor's "all clear" is a contract, not an absence of code paths);
 histogram merge/round-trip property tests pin the exact-aggregation
 claim vs numpy; timeline tests pin anchor-based clock alignment and the
-anchor-less rejection; regress tests pin the bench-diff findings schema.
+anchor-less rejection.
 """
 
 import contextlib
 import io
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -21,10 +19,6 @@ from sparkucx_tpu.utils.doctor import (Finding, Thresholds, build_view,
                                        diagnose, render_findings)
 from sparkucx_tpu.utils.metrics import (H_FETCH_FIRST, H_FETCH_WAIT,
                                         H_RETRY_MS, Histogram, Metrics)
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-import bench  # noqa: E402
 
 
 # -- synthetic snapshot builders -------------------------------------------
@@ -1207,59 +1201,6 @@ def test_v2_facade_doctor(mesh8, rng):
         fs = svc.doctor()
         assert isinstance(fs, list)
         assert svc.doctor("text").startswith("doctor:")
-
-
-# -- regression gating (bench --stage regress) -----------------------------
-def test_regress_compare_goldens():
-    base = {"metric": "m", "detail": {
-        "exchange_p50_ms": 10.0, "rate_gbps": 4.0, "compiles": 3,
-        "tiny_us": 1.0, "mystery": 7.0}}
-    cand = {"metric": "m", "detail": {
-        "exchange_p50_ms": 30.0,      # 3x slower -> critical
-        "rate_gbps": 2.0,             # halved -> warn (50%)
-        "compiles": 3,                # unchanged
-        "tiny_us": 2.0,               # 100% but < 0.05 ms floor
-        "mystery": 100.0}}            # unknown direction -> skipped
-    findings, compared, skipped = bench.regress_compare(base, cand)
-    by_metric = {f.evidence["metric"]: f for f in findings}
-    assert by_metric["detail.exchange_p50_ms"].grade == "critical"
-    assert by_metric["detail.rate_gbps"].grade == "warn"
-    assert "detail.tiny_us" not in by_metric          # noise floor
-    assert "detail.mystery" not in by_metric          # no guessed sign
-    assert skipped >= 1
-    assert all(f.rule == "perf_regression" for f in findings)
-    # improvement shows as info
-    findings2, _, _ = bench.regress_compare(cand, base)
-    assert any(f.rule == "perf_improvement" and f.grade == "info"
-               for f in findings2)
-
-
-def test_regress_stage_writes_findings_doc(tmp_path, capsys):
-    """Two artifacts in, one findings doc out — the acceptance shape."""
-    import argparse
-    base = tmp_path / "base.json"
-    cand = tmp_path / "cand.json"
-    base.write_text(json.dumps(
-        {"metric": "coldstart", "detail": {"first_ms": 100.0,
-                                           "compiles": 3}}))
-    cand.write_text(json.dumps(
-        {"metric": "coldstart", "detail": {"first_ms": 400.0,
-                                           "compiles": 19}}))
-    args = argparse.Namespace(
-        baseline=str(base), candidate=str(cand),
-        regress_warn_pct=50.0, regress_critical_pct=150.0,
-        gate_regress=False, regress_out=str(tmp_path / "regress.json"))
-    assert bench.stage_regress(args) == 0        # non-blocking default
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["metric"] == "bench_regress"
-    assert out["regressions"] == 2
-    assert not out["ok"]                          # critical fired
-    grades = {f["evidence"]["metric"]: f["grade"]
-              for f in out["findings"]}
-    assert grades["detail.first_ms"] == "critical"
-    assert grades["detail.compiles"] == "critical"
-    args.gate_regress = True
-    assert bench.stage_regress(args) == 2         # gated mode blocks
 
 
 # -- quota_starvation goldens (multi-tenant service plane) -----------------

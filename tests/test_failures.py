@@ -535,3 +535,155 @@ def test_manager_stale_epoch_fenced(manager_factory, rng):
     total = sum(k.shape[0] for _, (k, _) in mgr.read(h2).partitions())
     assert total == 2 * 32
     mgr.unregister_shuffle(912)
+
+
+# -- one fault per cell, across the read paths --------------------------------
+_CELL_MAPS, _CELL_ROWS, _CELL_PARTS, _CELL_WAVE_ROWS = 2, 256, 8, 64
+
+
+def _cell_stage(mgr, sid, keys, values):
+    h = mgr.register_shuffle(sid, _CELL_MAPS, _CELL_PARTS)
+    for m in range(_CELL_MAPS):
+        w = mgr.get_writer(h, m)
+        w.write(keys[m], values[m])
+        w.commit(_CELL_PARTS)
+    return h
+
+
+def _canonical(res):
+    """Per-partition rows sorted by (key, value row): partitioning and
+    content, order-free."""
+    out = []
+    for r in range(_CELL_PARTS):
+        k, v = res.partition(r)
+        order = np.lexsort(tuple(v.T[::-1]) + (k,))
+        out.append((k[order].tolist(), v[order].tolist()))
+    return out
+
+
+def _wire_values(k):
+    """Float value rows of moderate dynamic range, a function of the key
+    alone, so a received row's true values can be recomputed."""
+    base = (np.asarray(k) % 997).astype(np.float32)
+    return base[:, None] * 0.25 + np.arange(8, dtype=np.float32) * 0.5 + 1.0
+
+
+@pytest.mark.parametrize("cell", [
+    "device_sink", "combine_device_sink", "int8_waved", "hier_waved",
+    "distributed_replay", "distributed_dcn_failfast"])
+def test_fault_cell_recovers_to_oracle(manager_factory, tmp_path, cell):
+    """One armed fault per read path. Under failure.policy=replay the read
+    absorbs it: the replay lands on the clean run's plan family and
+    returns the oracle's rows, and the path's own properties hold
+    (device sink with no payload D2H before the consumer's view, a
+    combine folded on the device, the int8 wire within its per-row
+    rounding bound, a waved hierarchical read whose flight ring names
+    the faulted tier, one budget unit spent group-wide with no agreement
+    divergence). Under failfast a DCN straggler past its own deadline
+    surfaces as PeerLostError naming the tier, and a clean re-read
+    returns the oracle's rows."""
+    import jax
+
+    from sparkucx_tpu.utils.metrics import (C_AGREE_DIVERGENCE, C_D2H,
+                                            GLOBAL_METRICS)
+    conf = {"spark.shuffle.tpu.failure.policy": "replay",
+            "spark.shuffle.tpu.failure.replayBudget": "2",
+            "spark.shuffle.tpu.failure.collectiveTimeoutMs": "2000",
+            "spark.shuffle.tpu.network.timeoutMs": "2000"}
+    waved = cell in ("combine_device_sink", "int8_waved", "hier_waved")
+    if waved:
+        conf.update({"spark.shuffle.tpu.a2a.waveRows": str(_CELL_WAVE_ROWS),
+                     "spark.shuffle.tpu.a2a.waveDepth": "2"})
+    if cell in ("hier_waved", "distributed_replay",
+                "distributed_dcn_failfast"):
+        conf["spark.shuffle.tpu.mesh.numSlices"] = "2"
+    if cell == "int8_waved":
+        conf["spark.shuffle.tpu.a2a.wire"] = "int8"
+    if cell == "hier_waved":
+        conf.update({"spark.shuffle.tpu.flightRecorder.enabled": "true",
+                     "spark.shuffle.tpu.flightRecorder.dir": str(tmp_path)})
+    if cell == "distributed_dcn_failfast":
+        conf.update({"spark.shuffle.tpu.failure.policy": "failfast",
+                     "spark.shuffle.tpu.failure.dcn.timeoutMs": "300"})
+    mgr = manager_factory(conf)
+    node = mgr.node
+    node.is_distributed = cell.startswith("distributed")
+    rng = np.random.default_rng(0)
+    if cell == "int8_waved":
+        keys = [np.arange(_CELL_ROWS, dtype=np.int64) + m * (1 << 32)
+                for m in range(_CELL_MAPS)]
+        values = [_wire_values(k) for k in keys]
+    else:
+        keys = [rng.integers(-(1 << 62), 1 << 62, size=_CELL_ROWS)
+                for _ in range(_CELL_MAPS)]
+        values = [rng.integers(-(1 << 30), 1 << 30, size=(_CELL_ROWS, 2))
+                  .astype(np.int32) for _ in range(_CELL_MAPS)]
+    sink = "device" if cell.endswith("device_sink") else "host"
+    combine = "sum" if cell == "combine_device_sink" else None
+    site = {"int8_waved": "wave", "hier_waved": "tier.dcn",
+            "distributed_dcn_failfast": "tier.dcn"}.get(cell, "exchange")
+    try:
+        h0 = _cell_stage(mgr, 950, keys, values)
+        oracle = _canonical(mgr.read(h0, combine=combine, sink="host"))
+        if sink == "device":
+            mgr.unregister_shuffle(950)
+            h0 = _cell_stage(mgr, 950, keys, values)
+            mgr.read(h0, combine=combine, sink="device").close()
+        clean = mgr.report(950)
+        mgr.unregister_shuffle(950)
+        h = _cell_stage(mgr, 951, keys, values)
+        div0 = GLOBAL_METRICS.get(C_AGREE_DIVERGENCE)
+        if cell == "distributed_dcn_failfast":
+            node.faults.arm(site, delay_ms=1500)
+            with pytest.raises(PeerLostError, match="dcn"):
+                mgr.read(h)
+            # a pure delay never raises: its consulted hits are the firing
+            assert node.faults.stats()[site][0] >= 1
+            node.faults.disarm(site)
+            assert _canonical(mgr.read(h)) == oracle
+            return
+        node.faults.arm(site, fail_count=1)
+        d2h0 = GLOBAL_METRICS.get(C_D2H)
+        res = mgr.read(h, combine=combine, sink=sink)
+        if sink == "device":
+            passthru = jax.jit(lambda rows, nv: rows, donate_argnums=(0,))
+            outs = res.consume(
+                lambda c, rows, nv: (c or []) + [passthru(rows, nv)])
+            jax.block_until_ready(outs)
+            assert GLOBAL_METRICS.get(C_D2H) - d2h0 == 0
+            res = res.host_view(wave_rows=outs)
+        rep = mgr.report(951)
+        assert node.faults.stats()[site][1] == 1
+        assert rep.replays == 1
+        assert rep.plan_family == clean.plan_family
+        if cell == "int8_waved":
+            assert rep.wire == "int8" and rep.waves >= 2
+            got_keys = []
+            for r in range(_CELL_PARTS):
+                k, v = res.partition(r)
+                want = _wire_values(k)
+                bound = np.abs(want).max(axis=1, keepdims=True) / 127.0
+                assert (np.abs(v - want) <= bound + 1e-5).all()
+                got_keys += k.tolist()
+            assert sorted(got_keys) == sorted(np.concatenate(keys).tolist())
+        else:
+            assert _canonical(res) == oracle
+        if sink == "device":
+            assert rep.sink == "device"
+        if cell == "combine_device_sink":
+            assert rep.waves >= 2
+            assert len(outs) == 1 and rep.merge_ms > 0.0
+        if cell == "hier_waved":
+            assert rep.hierarchical and rep.tiers and rep.waves >= 2
+            assert all("ici_ms" in e and "dcn_ms" in e
+                       for e in rep.wave_timeline)
+            assert any(e.get("kind") == "tier_fault"
+                       and e.get("tier") == "dcn"
+                       for e in node.flight.events())
+        if cell == "distributed_replay":
+            assert rep.distributed
+            assert mgr._replay_counts.get(951) == 1
+            assert GLOBAL_METRICS.get(C_AGREE_DIVERGENCE) == div0
+    finally:
+        node.faults.disarm(site)
+        node.is_distributed = False

@@ -198,8 +198,7 @@ def test_hier_step_aot_proof():
     """The two-stage (ICI, DCN) exchange lowers for TPU at a 2x4
     topology via the local libtpu: BOTH collectives survive post-opt
     HLO — ICI groups of 4, DCN groups of 2 (the multi-slice half of the
-    distributed-backend evidence; artifact
-    bench_runs/r4_aot_hier_step.json). Skips where libtpu/topology
+    distributed-backend evidence). Skips where libtpu/topology
     support is unavailable."""
     import pytest as _pytest
 
@@ -232,8 +231,7 @@ def test_two_stage_proof_decision_closes_equal_size_hole():
 # impl x wire x mode x skew cells through the production manager on the
 # 2-D mesh. ONE cell runs in tier-1 (the suite sits within ~40 s of the
 # 870 s fence on this box — the PR-12 budget discipline); the rest are
-# slow-marked and verified under -m slow, with the per-cell contract
-# also gated in ci.yml (bench --stage hier). The in-tier cell is
+# slow-marked and verified under -m slow. The in-tier cell is
 # deliberately int8 x combine x one-hot: a single hot key is the shape
 # that stresses the RELAY combine (every row converges on one (slice,
 # device-column) relay, which must merge its whole slice's rows before

@@ -68,8 +68,9 @@ def test_bucket_cap_ceiling_clamped():
 def test_bucket_conf_gate_and_drift_collapse():
     """Bucketing off -> exact capacities; on -> a +/-20% drifting sweep
     of row counts lands on a handful of (cap_in, cap_out) signatures
-    instead of one per shape — the compile-amortization property the
-    coldstart bench measures end to end."""
+    instead of one per shape — the compile-amortization property
+    test_bucketed_reads_match_exact_and_compile_fewer checks through
+    the manager."""
     off = TpuShuffleConf({"spark.shuffle.tpu.a2a.capBuckets": "false",
                           "spark.shuffle.tpu.a2a.impl": "dense"},
                          use_env=False)
@@ -91,6 +92,44 @@ def test_bucket_conf_gate_and_drift_collapse():
         assert p_on.cap_out >= p_off.cap_out
     assert len(shapes_off) > 5 * len(shapes_on), (shapes_off, shapes_on)
 
+
+
+def test_bucketed_reads_match_exact_and_compile_fewer(manager_factory):
+    """The same drifting row counts read through the manager with
+    bucketing off and on: every partition is identical (bucketing only
+    pads capacities up, and padding never reaches a partition view),
+    and the bucketed reads compile fewer step programs."""
+    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.utils.metrics import COMPILE_PROGRAMS, GLOBAL_METRICS
+    rng = np.random.default_rng(3)
+    maps, R = 8, 16
+    counts = (512 * (1 + rng.uniform(-0.2, 0.2, size=6))).astype(int)
+    data = [[rng.integers(0, 1 << 40, size=int(n), dtype=np.int64)
+             for _ in range(maps)] for n in counts]
+    compiles, parts = {}, {}
+    for mode in ("false", "true"):
+        # a fresh step cache per mode: one mode's entries must not serve
+        # the other's reads
+        GLOBAL_STEP_CACHE.clear()
+        mgr = manager_factory({"spark.shuffle.tpu.a2a.capBuckets": mode})
+        p0 = GLOBAL_METRICS.get(COMPILE_PROGRAMS)
+        outs = []
+        for i, staged in enumerate(data):
+            h = mgr.register_shuffle(41000 + i, maps, R)
+            for m, keys in enumerate(staged):
+                w = mgr.get_writer(h, m)
+                w.write(keys)
+                w.commit(R)
+            res = mgr.read(h)
+            outs.append([res.partition(r)[0] for r in range(R)])
+            mgr.unregister_shuffle(41000 + i)
+        compiles[mode] = GLOBAL_METRICS.get(COMPILE_PROGRAMS) - p0
+        parts[mode] = outs
+    for exact, bucketed in zip(parts["false"], parts["true"]):
+        for a, b in zip(exact, bucketed):
+            np.testing.assert_array_equal(a, b)
+    assert compiles["false"] >= 4, compiles
+    assert compiles["true"] < compiles["false"], compiles
 
 def test_compile_conf_keys_round_trip():
     """The compile.* keys parse, validate, and appear in the

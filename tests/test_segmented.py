@@ -9,6 +9,8 @@ accumulation, carried lanes, and exact key/partition lanes; the
 conf/validation seam (read.mergeImpl); and the merge-fold program-family
 discipline (one merge program per family, 0 warm recompiles)."""
 
+import json
+
 import numpy as np
 import pytest
 
@@ -513,3 +515,35 @@ def test_gate_helper_is_the_single_skip_authority():
     assert r is not None and "backend='gpu'" in r
     impl, reason = S.resolve_kernel_impl("pallas", "gpu")
     assert (impl, reason) == ("jnp", "backend_unsupported")
+
+
+def test_kernelbench_smoke_emits_artifact_with_explicit_skip(tmp_path):
+    """Satellite: the kernel microbench on CPU — jnp arm runs and is
+    timed, the pallas arm records status=skipped with a reason (never
+    an interpret wall-time wearing a perf claim), parity still grades
+    via interpret, and the compile.step.programs invariant gates inside
+    the artifact (one program per shape family per impl on the first
+    pass, zero on the warm pass)."""
+    from sparkucx_tpu.ops.pallas.microbench import run_microbench
+    from sparkucx_tpu.utils.atomicio import atomic_write_json
+
+    doc = run_microbench(reps=1, rows_log2=8)
+    assert doc["ok"], doc["programs"]
+    assert doc["backend"] == "cpu" and doc["native_pallas"] is False
+    for c in doc["cases"]:
+        assert c["jnp"]["status"] == "ok"
+        assert c["jnp"]["rows_per_s"] > 0
+        assert c["pallas"]["status"] == "skipped"
+        assert c["pallas"]["reason"] == "backend_unsupported"
+        assert "rows_per_s" not in c["pallas"]
+        assert c["parity"]["status"] == "ok"
+        assert c["parity"]["mode"] == "interpret"
+        assert c["parity"]["ok"] is True
+    # the invariant the acceptance bar names, gated in the artifact
+    p = doc["programs"]
+    assert p["first_pass"] == p["expected"] > 0
+    assert p["warm_recompiles"] == 0 and p["ok"]
+    # the artifact lands as real JSON (the CLI --out path)
+    path = str(tmp_path / "kernelbench.json")
+    atomic_write_json(path, doc, indent=1)
+    assert json.load(open(path))["metric"] == "kernelbench"

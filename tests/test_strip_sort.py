@@ -200,8 +200,7 @@ def test_manager_e2e_strips(rng):
 @pytest.mark.slow
 def test_strip_step_aot_proof():
     """The strip-sorted step lowers for the v5e chip via the local
-    libtpu: pure sort — no collective, no scatter
-    (bench_runs/r4_aot_strip_step.json carries the full-shape run).
+    libtpu: pure sort — no collective, no scatter.
     Skips where libtpu/topology construction is unavailable."""
     from sparkucx_tpu.shuffle.aot import aot_compile_strip_step
     rep = aot_compile_strip_step(strips=16, rows=1 << 16)

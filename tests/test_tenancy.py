@@ -425,6 +425,61 @@ def test_v1_async_futures_match_sync(mesh8):
         svc.stop()
 
 
+
+@pytest.mark.parametrize("cell", ["solo", "fair", "starved"])
+def test_async_plane_whale_flood_cells(mesh8, cell):
+    """Minnow reads on the async facade plane alone (solo), behind a
+    whale's flood of reads under fair share (fair), and behind the same
+    flood under strict-FIFO admission (starved): every future resolves
+    to its oracle rows and the labeled read counters count each
+    tenant's reads. Whether the doctor's quota_starvation rule fires
+    needs admission waits past its 300 ms floor, which this shape does
+    not reach on every host; the rule's goldens in test_doctor.py and
+    test_cross_grants_observed hold the firing and its discriminator."""
+    from sparkucx_tpu.service import connect
+    from sparkucx_tpu.utils.metrics import labeled
+    extra = {"spark.shuffle.tpu.tenant.asyncWorkers": "16",
+             "spark.shuffle.tpu.tenant.minnow.priority": "high",
+             "spark.shuffle.tpu.tenant.whale.priority": "batch"}
+    if cell == "starved":
+        extra["spark.shuffle.tpu.tenant.fairShare"] = "false"
+    svc = connect(_service_conf(extra), use_env=False)
+    rng = np.random.default_rng(7)
+
+    def stage(sid, tenant, maps, rows):
+        h = svc.register_shuffle(sid, maps, 8, tenant=tenant)
+        keys = rng.integers(0, 1 << 20, maps * rows).astype(np.int64)
+        for m in range(maps):
+            svc.write(h, m, keys[m * rows:(m + 1) * rows])
+        return h, np.sort(keys)
+
+    try:
+        minnows = [stage(100 + i, "minnow", 2, 128) for i in range(4)]
+        whale = stage(99, "whale", 4, 512) if cell != "solo" else None
+        svc.read(minnows[0][0])                 # warm both families
+        futs = []
+        if whale is not None:
+            svc.read(whale[0])
+            # the whale floods admission before the minnows arrive
+            futs += [(svc.read_async(whale[0]), whale[1])
+                     for _ in range(4)]
+        futs += [(svc.read_async(h), want) for h, want in minnows]
+        for fut, want in futs:
+            res = fut.result(timeout=120)
+            got = np.sort(np.concatenate(
+                [res.partition(r)[0] for r in range(8)]))
+            np.testing.assert_array_equal(got, want)
+        counters = svc.stats("json")["counters"]
+        assert counters[labeled("shuffle.read.count",
+                                tenant="minnow")] == 1 + len(minnows)
+        whale_reads = labeled("shuffle.read.count", tenant="whale")
+        if whale is None:
+            assert whale_reads not in counters
+        else:
+            assert counters[whale_reads] == 1 + 4
+    finally:
+        svc.stop()
+
 def test_v2_async_futures(mesh8):
     from sparkucx_tpu.compat.v2 import ShuffleDependency, ShuffleServiceV2
     svc = ShuffleServiceV2(TpuShuffleConf(_service_conf(), use_env=False))

@@ -220,9 +220,7 @@ def test_gpu_capability_gates_without_a_gpu():
 # contract has NO other home stay in-tier (per-tier accounting + cross
 # oracle + counters, the admission pin, the structural cache key); the
 # device-sink / per-tier-deadline / replay / waved-timeline e2e legs are
-# slow-marked — each is ALSO a dedicated ci.yml gate (`bench --stage
-# hier` drills the straggler + walls; the chaos hier×replay×waved cell
-# gates replay-to-oracle with the tier named) and all run under -m slow.
+# slow-marked and run under -m slow.
 @pytest.fixture(scope="module")
 def hier_mgr():
     from sparkucx_tpu.runtime.node import TpuNode

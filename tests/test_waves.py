@@ -1,6 +1,6 @@
 """Wave-pipelined exchange (a2a.waveRows) — the streaming-read suite.
 
-Pins the three pipeline contracts the bench artifact claims at scale:
+Pins the three pipeline contracts:
 waved results are equivalent to single-shot (the fuzz sweep in
 test_fuzz_e2e composes this with random schemas), wave *i+1*'s pack
 starts before wave *i*'s result is forced (overlap proof), and an
@@ -148,6 +148,24 @@ def test_overlap_proof(manager_factory):
     for t in tl:
         assert t["forced_ms"] >= t["pack_start_ms"] + t["pack_ms"]
 
+
+
+def test_waved_read_compiles_one_program_for_all_waves(manager_factory):
+    """Every wave of a waved read dispatches through ONE compiled step
+    (the wave plan's signature is fixed), and a warm read of the same
+    shape compiles nothing."""
+    from sparkucx_tpu.shuffle.stepcache import GLOBAL_STEP_CACHE
+    from sparkucx_tpu.utils.metrics import COMPILE_PROGRAMS, GLOBAL_METRICS
+    mgr = manager_factory({"spark.shuffle.tpu.a2a.waveRows": "256"})
+    GLOBAL_STEP_CACHE.clear()
+    p0 = GLOBAL_METRICS.get(COMPILE_PROGRAMS)
+    _, _, rep, _ = _run_job(mgr, 61020, 4, 8, np.random.default_rng(6),
+                            2048, 1 << 40)
+    assert rep.waves >= 4
+    assert GLOBAL_METRICS.get(COMPILE_PROGRAMS) - p0 == 1
+    p1 = GLOBAL_METRICS.get(COMPILE_PROGRAMS)
+    _run_job(mgr, 61021, 4, 8, np.random.default_rng(7), 2048, 1 << 40)
+    assert GLOBAL_METRICS.get(COMPILE_PROGRAMS) - p1 == 0
 
 def test_wave_depth_one_serializes(manager_factory):
     """depth=1 degenerates to serial per-wave execution: correct results,

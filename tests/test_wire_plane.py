@@ -69,7 +69,7 @@ def test_wire_row_words_per_tier():
     assert wire_row_words(q, 10) == 2 + 3          # keys + packed + scale
     q64 = _plan(wire="int8", wire_words=64)
     assert wire_row_words(q64, 66) == 2 + 17
-    # the <=0.30x contract-shape arithmetic the bench gate pins
+    # the <=0.30x narrowing at the 64-lane contract row
     assert (2 + 17) / 66 < 0.30
     assert plan_takes_seed(q) and not plan_takes_seed(raw)
     assert not plan_takes_seed(lossless)
