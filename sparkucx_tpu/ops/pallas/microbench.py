@@ -45,12 +45,14 @@ _FLIP = np.int32(-0x80000000)
 
 def make_sorted_rows(rng, n: int, cap: int, num_parts: int, width: int,
                      groups: int, sum_words: int,
-                     float_vals: bool = False):
+                     float_vals: bool = False, zipf: float = 0.0):
     """Sorted-contract transport rows for the kernels: ``n`` valid rows
     in (part, key) order, sentinel-padded to ``cap``, carried lanes
     (past ``sum_words``) per-key constants per the data contract (the
     unstable keysort makes the representative row arbitrary, so any
-    non-constant carried lane would be a parity bug in the DATA)."""
+    non-constant carried lane would be a parity bug in the DATA).
+    ``zipf`` > 0 draws each row's group with weight ``rank ** -zipf``
+    (word frequencies), else uniformly."""
     import jax.numpy as jnp
     groups = max(1, min(groups, n)) if n else 1
     part = np.sort(rng.integers(0, num_parts, size=groups)
@@ -60,8 +62,11 @@ def make_sorted_rows(rng, n: int, cap: int, num_parts: int, width: int,
                       dtype=np.int64).astype(np.int32)
     order = np.lexsort((lo ^ _FLIP, hi, part))
     part, hi, lo = part[order], hi[order], lo[order]
-    gid = np.sort(rng.integers(0, groups, size=n)) if n \
-        else np.zeros(0, np.int64)
+    if zipf > 0:
+        w = np.arange(1, groups + 1, dtype=np.float64) ** -zipf
+        gid = np.sort(rng.choice(groups, size=n, p=w / w.sum()))
+    else:
+        gid = np.sort(rng.integers(0, groups, size=n))
     sw = sum_words if sum_words > 0 else width - 2
     rows = np.zeros((cap, width), np.int32)
     p = np.full(cap, num_parts, np.int32)
@@ -87,7 +92,9 @@ def default_cases(rows_log2: int = 13) -> List[dict]:
     signal (2^rows_log2 rows); the small ones pin the ragged corners
     (non-tile-aligned, single-group, many-tiles-one-segment) so a
     blocked-kernel regression on an edge shows up as a parity failure
-    here before it ships."""
+    here before it ships. ``sparse_wave`` is a WordCount receive buffer
+    on one of four chips: 3-word rows, a 23rd of the capacity live,
+    Zipf(1.1) words, so it times the tiles the kernel skips."""
     n = 1 << rows_log2
     return [
         dict(name="big_i32", n=n, cap=n, parts=16, width=8, groups=256,
@@ -101,6 +108,9 @@ def default_cases(rows_log2: int = 13) -> List[dict]:
              sum_words=0, float_vals=False),
         dict(name="wire_int8_fused", n=n, cap=n, parts=16, width=6,
              groups=256, sum_words=0, float_vals=True, wire=True),
+        dict(name="sparse_wave", n=max(1, n // 23), cap=n, parts=64,
+             width=3, groups=max(1, n // 23), sum_words=0,
+             float_vals=False, zipf=1.1),
     ]
 
 
@@ -137,7 +147,7 @@ def _case_inputs(case: dict, seed: int = 0):
     rows, part = make_sorted_rows(
         rng, case["n"], case["cap"], case["parts"], case["width"],
         case["groups"], case["sum_words"],
-        float_vals=case["float_vals"])
+        float_vals=case["float_vals"], zipf=case.get("zipf", 0.0))
     if case.get("wire"):
         from sparkucx_tpu.shuffle.alltoall import wire_pack_rows
         vw = case["width"] - 2

@@ -62,10 +62,13 @@ kernel OUTPUT are zeroed with sentinel partition (the jnp epilogues
 mask them), so the two impls agree byte-for-byte on the whole buffer.
 
 VMEM posture: the reduce is gridded in and out (rows travel transposed
-through HBM, see its kernel), so it runs at any capacity. The merge
-keeps both sorted runs VMEM-resident, laid out 128 lanes wide, which
-caps it at :func:`merge_rows_cap` rows per fold (about 65k for 100 B
-rows); the device merge fold takes the jnp path above the cap.
+through HBM, see its kernel), so it runs at any capacity, and it works
+only on the tiles up to the last real row: a receive buffer sized for
+skew holds mostly sentinel tiles, which cost a grid step each and no
+DMA or compute. The merge keeps both sorted runs VMEM-resident, laid
+out 128 lanes wide, which caps it at :func:`merge_rows_cap` rows per
+fold (about 65k for 100 B rows); the device merge fold takes the jnp
+path above the cap.
 
 Impl resolution (``spark.shuffle.tpu.read.mergeImpl``) lives here too:
 :func:`resolve_kernel_impl` is THE seam deciding jnp vs pallas per
@@ -378,9 +381,9 @@ def merge_rows(
 
 # -- segment reduce --------------------------------------------------------
 
-def _segreduce_blocked_kernel(x_ref, o_ref, n_ref, state_ref, acc_ref,
-                              rep_ref, *, sum_words: int, float_acc: bool,
-                              num_parts: int, tile: int, num_tiles: int,
+def _segreduce_blocked_kernel(live_ref, x_ref, o_ref, n_ref, state_ref,
+                              acc_ref, rep_ref, *, sum_words: int,
+                              float_acc: bool, num_parts: int, tile: int,
                               width: int, wire_words: int, w_in: int):
     """One input tile of the tiled segment-reduce run-scan.
 
@@ -394,12 +397,21 @@ def _segreduce_blocked_kernel(x_ref, o_ref, n_ref, state_ref, acc_ref,
     continuing the carry get id 0) -> per-segment partial sums by
     one-hot matmul (split-16 exact for ints) -> CLOSED segments (all
     but the last) emitted at the head of this tile's own output block,
-    the rest of the block marked with the sentinel partition; the final
-    grid step also places the still-open segment in its block's next
-    slot and stamps n_out.  Every store is a whole-block write, and the
-    output is gridded like the input, so VMEM holds a few small blocks
-    whatever the capacity; the wrapper compacts the per-tile heads into
-    one dense prefix by one gather, its index built with no search
+    the rest of the block marked with the sentinel partition; the last
+    LIVE tile also places the still-open segment in its block's next
+    slot and stamps n_out.
+
+    ``live_ref`` (scalar prefetch) holds the live tiles: those up to
+    the last row with a real partition.  The tiles past them hold only
+    sentinels, so their steps run no body, and the index maps repeat
+    the last live block for them, so they move nothing in or out; their
+    output blocks stay unwritten and the wrapper counts them empty.
+    With no live tile, ``_init`` leaves n_out 0.
+
+    Every store is a whole-block write, and the output is gridded like
+    the input, so VMEM holds a few small blocks whatever the capacity;
+    the wrapper compacts the per-tile heads into one dense prefix by
+    one gather, its index built with no search
     (``_compact_src``: each block's step to the next block's offset
     scattered to its dense end, then a prefix sum; the offset rises
     with the block because a tile emits fewer rows than its block
@@ -418,6 +430,7 @@ def _segreduce_blocked_kernel(x_ref, o_ref, n_ref, state_ref, acc_ref,
     scan — byte-extraction arithmetic instead of int8 bitcasts so the
     prologue stays reshape-free for Mosaic."""
     t = pl.program_id(0)
+    live = live_ref[0]
     acc_zero = jnp.zeros((1, sum_words),
                          jnp.float32 if float_acc else jnp.int32)
 
@@ -430,133 +443,138 @@ def _segreduce_blocked_kernel(x_ref, o_ref, n_ref, state_ref, acc_ref,
         state_ref[0, 4] = jnp.int32(num_parts)   # open rep's partition
         acc_ref[:] = acc_zero
         rep_ref[:] = jnp.zeros((1, width), jnp.int32)
+        n_ref[0, 0] = jnp.int32(0)               # no live tile: n_out 0
 
-    xs = x_ref[:].T                              # [tile, S_in]
-    raw = xs[:, :w_in]                           # [tile, W_in]
-    prt = xs[:, w_in:w_in + 1]                   # [tile, 1]
-    if wire_words > 0:
-        # fused dequant prologue: wire cols = [key lo, key hi,
-        # packed int8 x qw, f32 scale]; rebuild the full-width f32
-        # row in int32 bit-pattern lanes (wire_unpack_rows semantics:
-        # val = int8 * row scale)
-        qw = -(-wire_words // 4)
-        scale = jax.lax.bitcast_convert_type(raw[:, 2 + qw:3 + qw],
-                                             jnp.float32)
-        cols = []
-        for j in range(wire_words):
-            w8 = (raw[:, 2 + j // 4:3 + j // 4] >> (8 * (j % 4))) & 0xFF
-            signed = (w8 ^ 0x80) - 0x80          # sign-extend int8
-            cols.append(signed.astype(jnp.float32) * scale)
-        vals = jax.lax.bitcast_convert_type(
-            jnp.concatenate(cols, axis=1), jnp.int32)
-        rows = jnp.concatenate([raw[:, :2], vals], axis=1)
-    else:
-        rows = raw
+    @pl.when(t < live)
+    def _tile():
+        xs = x_ref[:].T                          # [tile, S_in]
+        raw = xs[:, :w_in]                       # [tile, W_in]
+        prt = xs[:, w_in:w_in + 1]               # [tile, 1]
+        if wire_words > 0:
+            # fused dequant prologue: wire cols = [key lo, key hi,
+            # packed int8 x qw, f32 scale]; rebuild the full-width f32
+            # row in int32 bit-pattern lanes (wire_unpack_rows semantics:
+            # val = int8 * row scale)
+            qw = -(-wire_words // 4)
+            scale = jax.lax.bitcast_convert_type(raw[:, 2 + qw:3 + qw],
+                                                 jnp.float32)
+            cols = []
+            for j in range(wire_words):
+                w8 = (raw[:, 2 + j // 4:3 + j // 4] >> (8 * (j % 4))) & 0xFF
+                signed = (w8 ^ 0x80) - 0x80          # sign-extend int8
+                cols.append(signed.astype(jnp.float32) * scale)
+            vals = jax.lax.bitcast_convert_type(
+                jnp.concatenate(cols, axis=1), jnp.int32)
+            rows = jnp.concatenate([raw[:, :2], vals], axis=1)
+        else:
+            rows = raw
 
-    optr = state_ref[0, 0]
-    open_ = optr >= 0
-    hi, lo = rows[:, 1:2], rows[:, 0:1]
-    valid = prt < num_parts
-    prev_p = jnp.concatenate([state_ref[0, 1].reshape(1, 1), prt[:-1]])
-    prev_h = jnp.concatenate([state_ref[0, 2].reshape(1, 1), hi[:-1]])
-    prev_l = jnp.concatenate([state_ref[0, 3].reshape(1, 1), lo[:-1]])
-    is_new = valid & ((prt != prev_p) | (hi != prev_h) | (lo != prev_l))
+        optr = state_ref[0, 0]
+        open_ = optr >= 0
+        hi, lo = rows[:, 1:2], rows[:, 0:1]
+        valid = prt < num_parts
+        prev_p = jnp.concatenate([state_ref[0, 1].reshape(1, 1), prt[:-1]])
+        prev_h = jnp.concatenate([state_ref[0, 2].reshape(1, 1), hi[:-1]])
+        prev_l = jnp.concatenate([state_ref[0, 3].reshape(1, 1), lo[:-1]])
+        is_new = valid & ((prt != prev_p) | (hi != prev_h) | (lo != prev_l))
 
-    # inclusive cumsum by triangular matmul: sid 0 = carry continuation
-    tril = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-            >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-            ).astype(jnp.float32)
-    sid = jax.lax.dot(tril, is_new.astype(jnp.float32),
-                      preferred_element_type=jnp.float32,
-                      precision=_EXACT).astype(jnp.int32)
-    nnew = sid[tile - 1, 0]
+        # inclusive cumsum by triangular matmul: sid 0 = carry continuation
+        tril = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+                >= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+                ).astype(jnp.float32)
+        sid = jax.lax.dot(tril, is_new.astype(jnp.float32),
+                          preferred_element_type=jnp.float32,
+                          precision=_EXACT).astype(jnp.int32)
+        nnew = sid[tile - 1, 0]
 
-    # per-segment partial sums, sids 0..tile: oh[s, i] = (sid_i == s)
-    sid_row = jnp.reshape(sid, (1, tile))
-    valid_row = jnp.reshape(valid, (1, tile))
-    oh_sum = ((sid_row == jax.lax.broadcasted_iota(
-        jnp.int32, (tile + 1, tile), 0)) & valid_row).astype(jnp.float32)
-    lanes = rows[:, 2:2 + sum_words]
-    if float_acc:
-        fl = jax.lax.bitcast_convert_type(lanes, jnp.float32)
-        fl = jnp.where(valid, fl, jnp.float32(0))
-        sums = jax.lax.dot(oh_sum, fl, preferred_element_type=jnp.float32,
-                           precision=_EXACT)
-    else:
-        sums = _exact_gather(oh_sum, jnp.where(valid, lanes, 0))
+        # per-segment partial sums, sids 0..tile: oh[s, i] = (sid_i == s)
+        sid_row = jnp.reshape(sid, (1, tile))
+        valid_row = jnp.reshape(valid, (1, tile))
+        oh_sum = ((sid_row == jax.lax.broadcasted_iota(
+            jnp.int32, (tile + 1, tile), 0)) & valid_row).astype(jnp.float32)
+        lanes = rows[:, 2:2 + sum_words]
+        if float_acc:
+            fl = jax.lax.bitcast_convert_type(lanes, jnp.float32)
+            fl = jnp.where(valid, fl, jnp.float32(0))
+            sums = jax.lax.dot(oh_sum, fl, preferred_element_type=jnp.float32,
+                               precision=_EXACT)
+        else:
+            sums = _exact_gather(oh_sum, jnp.where(valid, lanes, 0))
 
-    # closed segments this tile: sids [shift, nnew) at slots optr+shift..
-    shift = jnp.where(open_, 0, 1)
-    total0 = acc_ref[:] + sums[0:1]              # carry + continuation
-    sums_sel = jnp.where(open_, sums[0:tile], sums[1:tile + 1])
-    sums_sel = jnp.concatenate(
-        [jnp.where(open_, total0, sums_sel[0:1]), sums_sel[1:]])
+        # closed segments this tile: sids [shift, nnew) at slots optr+shift..
+        shift = jnp.where(open_, 0, 1)
+        total0 = acc_ref[:] + sums[0:1]              # carry + continuation
+        sums_sel = jnp.where(open_, sums[0:tile], sums[1:tile + 1])
+        sums_sel = jnp.concatenate(
+            [jnp.where(open_, total0, sums_sel[0:1]), sums_sel[1:]])
 
-    # representative rows: emit row r <- the is_new row of sid r+shift
-    rvals = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0) + shift
-    oh_rep = ((sid_row == rvals)
-              & jnp.reshape(is_new, (1, tile))).astype(jnp.float32)
-    reps = _exact_gather(oh_rep, rows)
-    rparts = _small_gather(oh_rep, prt)
-    row0 = jnp.where(open_, rep_ref[:], reps[0:1])
-    part0 = jnp.where(open_, state_ref[0, 4].reshape(1, 1), rparts[0:1])
-    reps = jnp.concatenate([row0, reps[1:]])
-    rparts = jnp.concatenate([part0, rparts[1:]])
-    words = sums_sel if not float_acc else \
-        jax.lax.bitcast_convert_type(sums_sel, jnp.int32)
-    # carried lanes only when the row has any: Mosaic refuses the
-    # zero-width slice a keys-plus-sums row would otherwise take
-    carried = width > 2 + sum_words
-    emit = jnp.concatenate(
-        [reps[:, :2], words] + ([reps[:, 2 + sum_words:]] if carried
-                                else []), axis=1)
+        # representative rows: emit row r <- the is_new row of sid r+shift
+        rvals = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0) + shift
+        oh_rep = ((sid_row == rvals)
+                  & jnp.reshape(is_new, (1, tile))).astype(jnp.float32)
+        reps = _exact_gather(oh_rep, rows)
+        rparts = _small_gather(oh_rep, prt)
+        row0 = jnp.where(open_, rep_ref[:], reps[0:1])
+        part0 = jnp.where(open_, state_ref[0, 4].reshape(1, 1), rparts[0:1])
+        reps = jnp.concatenate([row0, reps[1:]])
+        rparts = jnp.concatenate([part0, rparts[1:]])
+        words = sums_sel if not float_acc else \
+            jax.lax.bitcast_convert_type(sums_sel, jnp.int32)
+        # carried lanes only when the row has any: Mosaic refuses the
+        # zero-width slice a keys-plus-sums row would otherwise take
+        carried = width > 2 + sum_words
+        emit = jnp.concatenate(
+            [reps[:, :2], words] + ([reps[:, 2 + sum_words:]] if carried
+                                    else []), axis=1)
 
-    nclosed = nnew - shift
+        nclosed = nnew - shift
 
-    # roll the scratch forward: the LAST segment stays open
-    optr2 = optr + nnew
-    oh_last = ((sid_row == nnew)
-               & jnp.reshape(is_new, (1, tile))).astype(jnp.float32)
-    # sums[0] is provably zero when no segment is open (a valid row can
-    # only get sid 0 by continuing a previous run), so the nnew == 0 arm
-    # is correct in every open/closed state
-    acc_ref[:] = jnp.where(nnew == 0, acc_ref[:] + sums[0:1],
-                           _pick_row(sums, nnew, float_acc))
-    rep_ref[:] = jnp.where(nnew > 0, _exact_gather(oh_last, rows),
-                           rep_ref[:])
-    state_ref[0, 4] = jnp.where(nnew > 0,
-                                _small_gather(oh_last, prt)[0, 0],
-                                state_ref[0, 4])
-    state_ref[0, 0] = optr2
-    state_ref[0, 1] = prt[tile - 1, 0]
-    state_ref[0, 2] = rows[tile - 1, 1]
-    state_ref[0, 3] = rows[tile - 1, 0]
-    n_ref[0, 0] = optr2 + 1
+        # roll the scratch forward: the LAST segment stays open
+        optr2 = optr + nnew
+        oh_last = ((sid_row == nnew)
+                   & jnp.reshape(is_new, (1, tile))).astype(jnp.float32)
+        # sums[0] is provably zero when no segment is open (a valid row can
+        # only get sid 0 by continuing a previous run), so the nnew == 0 arm
+        # is correct in every open/closed state
+        acc_ref[:] = jnp.where(nnew == 0, acc_ref[:] + sums[0:1],
+                               _pick_row(sums, nnew, float_acc))
+        rep_ref[:] = jnp.where(nnew > 0, _exact_gather(oh_last, rows),
+                               rep_ref[:])
+        state_ref[0, 4] = jnp.where(nnew > 0,
+                                    _small_gather(oh_last, prt)[0, 0],
+                                    state_ref[0, 4])
+        state_ref[0, 0] = optr2
+        state_ref[0, 1] = prt[tile - 1, 0]
+        state_ref[0, 2] = rows[tile - 1, 1]
+        state_ref[0, 3] = rows[tile - 1, 0]
+        n_ref[0, 0] = optr2 + 1
 
-    # this tile's block: closed heads, then (last tile) the open segment
-    blk = o_ref.shape[1]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
-    out_rows = jnp.concatenate(
-        [emit, jnp.zeros((blk - tile, width), jnp.int32)])
-    out_part = jnp.where(
-        slot < nclosed,
-        jnp.concatenate([rparts, jnp.zeros((blk - tile, 1), jnp.int32)]),
-        jnp.int32(num_parts))
-    acc = acc_ref[:]
-    w = acc if not float_acc else \
-        jax.lax.bitcast_convert_type(acc, jnp.int32)
-    rep = rep_ref[:]
-    flush_row = jnp.concatenate(
-        [rep[:, :2], w] + ([rep[:, 2 + sum_words:]] if carried else []),
-        axis=1)
-    at_flush = (slot == jnp.maximum(nclosed, 0)) \
-        & (t == num_tiles - 1) & (optr2 >= 0)
-    out_rows = jnp.where(at_flush, flush_row, out_rows)
-    out_part = jnp.where(at_flush, state_ref[0, 4], out_part)
-    s_out = o_ref.shape[0]
-    o_ref[:] = jnp.concatenate(
-        [out_rows, out_part,
-         jnp.zeros((blk, s_out - width - 1), jnp.int32)], axis=1).T
+        # this tile's block: closed heads, then (last live tile) the
+        # open segment
+        blk = o_ref.shape[1]
+        slot = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+        out_rows = jnp.concatenate(
+            [emit, jnp.zeros((blk - tile, width), jnp.int32)])
+        out_part = jnp.where(
+            slot < nclosed,
+            jnp.concatenate([rparts,
+                             jnp.zeros((blk - tile, 1), jnp.int32)]),
+            jnp.int32(num_parts))
+        acc = acc_ref[:]
+        w = acc if not float_acc else \
+            jax.lax.bitcast_convert_type(acc, jnp.int32)
+        rep = rep_ref[:]
+        flush_row = jnp.concatenate(
+            [rep[:, :2], w] + ([rep[:, 2 + sum_words:]] if carried
+                               else []), axis=1)
+        at_flush = (slot == jnp.maximum(nclosed, 0)) \
+            & (t == live - 1) & (optr2 >= 0)
+        out_rows = jnp.where(at_flush, flush_row, out_rows)
+        out_part = jnp.where(at_flush, state_ref[0, 4], out_part)
+        s_out = o_ref.shape[0]
+        o_ref[:] = jnp.concatenate(
+            [out_rows, out_part,
+             jnp.zeros((blk, s_out - width - 1), jnp.int32)], axis=1).T
 
 
 def _pick_row(sums: jnp.ndarray, idx, float_acc: bool) -> jnp.ndarray:
@@ -579,7 +597,7 @@ def _segreduce_pallas(rows, part, num_parts: int, sum_words: int,
     cap, w_in = rows.shape
     width = w_in if width is None else width
     tile = _TILE
-    nt = max(1, -(-cap // tile))
+    nt = max(1, reduce_tiles(cap))
     cap_pad = nt * tile
     s_in = -(-(w_in + 1) // 8) * 8     # row lanes + partition, sublanes
     s_out = -(-(width + 1) // 8) * 8
@@ -592,6 +610,18 @@ def _segreduce_pallas(rows, part, num_parts: int, sum_words: int,
     pad = jnp.zeros((cap_pad - cap, s_in), jnp.int32).at[:, w_in].set(
         num_parts)
     xt = jnp.concatenate([x, pad]).T
+    # the live extent: tiles past the last real row hold only sentinels,
+    # so the kernel skips them (the kernel docstring says how); one
+    # reduction of the partition lane, whatever the rows' order
+    extent = jnp.max(jnp.where(part.reshape(cap) < num_parts,
+                               jnp.arange(1, cap + 1, dtype=jnp.int32), 0),
+                     initial=0)
+    live_tiles = reduce_tiles(extent).astype(jnp.int32)
+
+    def _clamped(t, live_ref):
+        # a dead step repeats the last live block: no DMA in or out
+        return 0, jnp.minimum(t, jnp.maximum(live_ref[0] - 1, 0))
+
     acc_dt = jnp.float32 if float_acc else jnp.int32
     kw = {}
     if not interpret:
@@ -601,34 +631,46 @@ def _segreduce_pallas(rows, part, num_parts: int, sum_words: int,
         functools.partial(
             _segreduce_blocked_kernel, sum_words=sum_words,
             float_acc=float_acc, num_parts=num_parts, tile=tile,
-            num_tiles=nt, width=width, wire_words=wire_words, w_in=w_in),
-        grid=(nt,),
+            width=width, wire_words=wire_words, w_in=w_in),
         out_shape=(jax.ShapeDtypeStruct((s_out, nt * blk), jnp.int32),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec((s_in, tile), lambda t: (0, t))],
-        # n_out is a scalar: Mosaic stores scalars to SMEM only
-        out_specs=(pl.BlockSpec((s_out, blk), lambda t: (0, t)),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)),
-        scratch_shapes=[pltpu.SMEM((1, 8), jnp.int32),
-                        pltpu.VMEM((1, sum_words), acc_dt),
-                        pltpu.VMEM((1, width), jnp.int32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nt,),
+            in_specs=[pl.BlockSpec((s_in, tile), _clamped)],
+            # n_out is a scalar: Mosaic stores scalars to SMEM only
+            out_specs=(pl.BlockSpec((s_out, blk), _clamped),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)),
+            scratch_shapes=[pltpu.SMEM((1, 8), jnp.int32),
+                            pltpu.VMEM((1, sum_words), acc_dt),
+                            pltpu.VMEM((1, width), jnp.int32)]),
         interpret=interpret,
         # the name its op carries in the compiled program, so a
         # profiler trace gives the kernel's own device time
         name="segreduce_blocked",
         **kw,
-    )(xt)
+    )(live_tiles.reshape(1), xt)
     # compact the per-tile heads (each block's live rows are a prefix)
     # into one dense prefix of ``cap`` rows by one gather; its index is
     # a prefix sum of per-block steps scattered to the blocks' dense
     # ends, no search: the block offset it builds rises strictly, as a
-    # tile emits fewer than ``blk`` rows
+    # tile emits fewer than ``blk`` rows. The kernel never writes the
+    # blocks past the live tiles, so their counts are set to 0: what
+    # they hold is not rows
     part2 = out_t[width]
     counts = (part2.reshape(nt, blk) < num_parts).sum(
         axis=1, dtype=jnp.int32)
+    counts = jnp.where(jnp.arange(nt) < live_tiles, counts, 0)
     src = _compact_src(counts, cap, blk)
     out = jnp.take(out_t[:width + 1], src, axis=1).T     # [cap, width + 1]
     return out[:, :width], out[:, width:], n
+
+
+def reduce_tiles(rows):
+    """Grid tiles the blocked segment-reduce spends on ``rows`` rows (an
+    int or an integer array): the whole grid of a buffer's capacity, or
+    its live tiles of the rows that reached it."""
+    return -(-rows // _TILE)
 
 
 def _compact_src(counts: jnp.ndarray, cap: int, blk: int) -> jnp.ndarray:
@@ -827,4 +869,5 @@ def merge_reduce_rows(
 __all__ = ["merge_rows", "segment_reduce_rows", "segment_reduce_wire_rows",
            "merge_reduce_rows",
            "blocked_compile_supported", "kernel_gate_reason",
-           "resolve_kernel_impl", "pallas_reduce_supported"]
+           "resolve_kernel_impl", "pallas_reduce_supported",
+           "reduce_tiles"]

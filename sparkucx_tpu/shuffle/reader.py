@@ -183,6 +183,27 @@ def combine_status(overflow, rows_recv, rows_out):
                             for x in (overflow, rows_recv, rows_out)])
 
 
+def reduce_rows_scanned(plan: ShufflePlan, width: int) -> int:
+    """Rows of a shard's receive buffer that its combining step hands the
+    Pallas segment-reduce (the kernel's grid covers them in 128-row
+    tiles), 0 where the step runs no such kernel: a plain or ordered
+    read, the jnp reduce, or one shard, whose map-side combine is the
+    whole reduce."""
+    if not plan.combine or plan.kernel_impl != "pallas":
+        return 0
+    if plan.impl == "pallas":
+        return _pallas_recv_rows(plan, width)
+    return plan.cap_out if plan.num_shards > 1 else 0
+
+
+def _pallas_recv_rows(plan: ShufflePlan, width: int) -> int:
+    """The first-party transport's receive capacity a shard: ``cap_out``
+    aligned to its DMA chunk, plus a chunk of pad a sender."""
+    from sparkucx_tpu.ops.pallas.ragged_a2a import align_rows, chunk_rows_for
+    chunk = chunk_rows_for(wire_row_words(plan, width))
+    return int(align_rows(plan.cap_out, chunk)) + plan.num_shards * chunk
+
+
 def overflow_flags(last, num_shards: int) -> np.ndarray:
     """[num_shards] bool overflow verdicts from a step's last output on
     the host: [1] bool a shard, or a combining step's ``combine_status``,
@@ -395,7 +416,7 @@ def _pallas_step_body(plan: ShufflePlan, axis: str):
     part_fn = _make_part_fn(plan, R)
 
     from sparkucx_tpu.ops.pallas.ragged_a2a import (
-        align_rows, chunk_rows_for, pallas_ragged_all_to_all)
+        chunk_rows_for, pallas_ragged_all_to_all)
     from sparkucx_tpu.ops.partition import partition_major_sort_aligned
 
     seeded = plan_takes_seed(plan)
@@ -436,7 +457,7 @@ def _pallas_step_body(plan: ShufflePlan, axis: str):
             # int8 wire: the remote DMA moves packed rows; alignment pad
             # rows quantize to zeros and decode back to zeros
             srows = wire_pack_rows(srows, plan.wire_words, seed)
-        cap_eff = int(align_rows(plan.cap_out, chunk)) + Pn * chunk
+        cap_eff = _pallas_recv_rows(plan, width)
         # interpret resolves at trace time from the backend UNLESS the
         # plan pins it (plan.pallas_interpret) — an AOT compile from a
         # CPU host against a TPU topology must pin False or the
@@ -1935,6 +1956,17 @@ class PendingShuffle(PendingExchangeBase):
                     sp.set(rows_in=int(self._nvalid_host.sum()),
                            rows_wire=int(status[:, 1].sum()),
                            rows_out=int(status[:, 2].sum()))
+                    scanned = reduce_rows_scanned(
+                        self._plan, self._rows_host.shape[2])
+                    if scanned:
+                        # the kernel's grid, and the tiles that hold a
+                        # row: it skips the rest
+                        from sparkucx_tpu.ops.pallas.segmented import \
+                            reduce_tiles
+                        sp.set(reduce_tiles=self._plan.num_shards
+                               * int(reduce_tiles(scanned)),
+                               reduce_tiles_live=int(
+                                   reduce_tiles(status[:, 1]).sum()))
             if not overflowed:
                 break
             if self._attempt >= self._plan.max_retries:

@@ -8,7 +8,14 @@ with the overflow flag. A waved read's host cross-wave combine sets
 ``rows_in`` and ``rows_out`` on its ``shuffle.merge`` span. Each count
 is checked against numpy over the same staged pairs: map m is staged on
 shard m % P, and wave i is rows [i*W, (i+1)*W) of each shard's maps in
-order. Plain and ordered reads set none of them."""
+order. Plain and ordered reads set none of them.
+
+Where the receive side reduces with the Pallas segment-reduce
+(``read.mergeImpl=pallas``, on more than one shard or on the Pallas
+transport), the same span also carries ``reduce_tiles``, the kernel's
+grid of 128-row tiles over the receive capacity, and
+``reduce_tiles_live``, the tiles that hold a received row, each summed
+over shards."""
 
 import jax
 import numpy as np
@@ -20,8 +27,8 @@ COUNTS = ("rows_in", "rows_wire", "rows_out")
 _SID = [9100]
 
 
-def _manager(manager_factory, chips, waved):
-    conf = dict(TRACE_ON)
+def _manager(manager_factory, chips, waved, **extra):
+    conf = dict(TRACE_ON, **extra)
     if waved:
         conf["spark.shuffle.tpu.a2a.waveRows"] = str(WAVE)
     mgr = manager_factory(conf)
@@ -31,11 +38,11 @@ def _manager(manager_factory, chips, waved):
     return mgr
 
 
-def _maps(seed):
+def _maps(seed, vocab=VOCAB):
     """(keys, ones) per map: words from a small vocabulary, so the
     combines merge and every word turns up in every wave."""
     rng = np.random.default_rng(seed)
-    return [(rng.integers(0, VOCAB, size=N).astype(np.int64) * 7919 + 3,
+    return [(rng.integers(0, vocab, size=N).astype(np.int64) * 7919 + 3,
              np.ones((N, 1), np.int32)) for _ in range(M)]
 
 
@@ -49,21 +56,43 @@ def _stage(mgr, maps, **kw):
     return h
 
 
+def _shard_waves(maps, chips, wave):
+    """Each exchange's keys a shard stages: map m on shard m % chips,
+    wave i rows [i*wave, (i+1)*wave) of each shard's maps in order."""
+    shards = [np.concatenate([k for m, (k, _) in enumerate(maps)
+                              if m % chips == p]) for p in range(chips)]
+    longest = max(s.shape[0] for s in shards)
+    wave = wave or longest
+    return [[s[lo:lo + wave] for s in shards]
+            for lo in range(0, longest, wave)]
+
+
 def _expected(maps, chips, wave):
     """[(rows_in, rows_wire, rows_out)] of each exchange, by numpy: a
     shard's combine sends one row per distinct word it holds (the
     partition is a function of the word), the receive side keeps one per
     distinct word of the exchange."""
-    shards = [np.concatenate([k for m, (k, _) in enumerate(maps)
-                              if m % chips == p]) for p in range(chips)]
-    longest = max(s.shape[0] for s in shards)
-    wave = wave or longest
+    return [(sum(p.shape[0] for p in parts),
+             sum(np.unique(p).shape[0] for p in parts),
+             np.unique(np.concatenate(parts)).shape[0])
+            for parts in _shard_waves(maps, chips, wave)]
+
+
+def _rows_received(maps, chips, wave):
+    """[[rows each shard received] of each exchange]: every sender's
+    distinct words go to the shard that owns the word's partition."""
+    from sparkucx_tpu.shuffle.integrity import host_partition_ids
+    from sparkucx_tpu.shuffle.reader import _device_bounds
+    bounds = _device_bounds(R, chips)
     out = []
-    for lo in range(0, longest, wave):
-        parts = [s[lo:lo + wave] for s in shards]
-        out.append((sum(p.shape[0] for p in parts),
-                    sum(np.unique(p).shape[0] for p in parts),
-                    np.unique(np.concatenate(parts)).shape[0]))
+    for parts in _shard_waves(maps, chips, wave):
+        recv = np.zeros(chips, np.int64)
+        for p in parts:
+            words = np.unique(p)
+            owner = np.searchsorted(
+                bounds, host_partition_ids(words, R), side="right") - 1
+            recv += np.bincount(owner, minlength=chips)
+        out.append(recv)
     return out
 
 
@@ -122,6 +151,59 @@ def test_plain_and_ordered_reads_set_no_counts(manager_factory, mode,
             + mgr.node.tracer.spans("shuffle.merge")
         assert mgr.node.tracer.spans("shuffle.exchange.wait")
         assert not [s for s in spans if set(COUNTS) & set(s.attrs)]
+    finally:
+        mgr.unregister_shuffle(h.shuffle_id)
+        mgr.node.tracer.clear()
+
+
+def _kernel_rows(transport, chips, cap_out):
+    """Rows of a shard's receive buffer the segment-reduce scans, 0 where
+    it does not run: one shard's exchange on the dense transport has no
+    receive-side reduce; the Pallas transport's buffer is ``cap_out``
+    aligned to its DMA chunk, plus a chunk a sender."""
+    if transport == "dense":
+        return cap_out if chips > 1 else 0
+    from sparkucx_tpu.ops.pallas.ragged_a2a import align_rows, chunk_rows_for
+    chunk = chunk_rows_for(3)
+    return align_rows(cap_out, chunk) + chips * chunk
+
+
+@pytest.mark.parametrize("transport,chips,waved", [
+    ("dense", 1, False), ("dense", 1, True), ("dense", 4, False),
+    ("dense", 4, True), ("pallas", 1, False), ("pallas", 4, False)])
+def test_reduce_tile_counts_match_numpy(manager_factory, transport, chips,
+                                        waved):
+    """The segment-reduce's grid and live tiles on each exchange's span:
+    ``chips * ceil(rows it scans / 128)`` and the sum over shards of
+    ``ceil(rows received / 128)``. Where no such kernel runs, the spans
+    carry neither."""
+    mgr = _manager(manager_factory, chips, waved,
+                   **{"spark.shuffle.tpu.read.mergeImpl": "pallas",
+                      "spark.shuffle.tpu.a2a.impl": transport})
+    # a wide vocabulary, so a shard receives more than one tile of rows
+    maps = _maps(chips * 10 + waved + 1, vocab=4096)
+    h = _stage(mgr, maps)
+    try:
+        got = _drain(mgr.read(h, combine="sum"))
+        distinct = np.unique(np.concatenate([k for k, _ in maps])).shape[0]
+        assert sum(k.shape[0] for k, _ in got.values()) == distinct
+        spans = [s.attrs for s in mgr.node.tracer.spans(
+            "shuffle.exchange.wait") if "rows_wire" in s.attrs]
+        assert spans
+        scanned = _kernel_rows(transport, chips,
+                               mgr.report(h.shuffle_id).plan_bucket[1])
+        if not scanned:
+            assert not [a for a in spans if "reduce_tiles" in a
+                        or "reduce_tiles_live" in a]
+            return
+        recv = _rows_received(maps, chips, WAVE if waved else 0)
+        assert sorted((a["rows_wire"], a["reduce_tiles_live"])
+                      for a in spans) == sorted(
+            (int(r.sum()), int((-(-r // 128)).sum())) for r in recv)
+        assert {a["reduce_tiles"] for a in spans} == {
+            chips * -(-scanned // 128)}
+        assert all(a["reduce_tiles_live"] < a["reduce_tiles"]
+                   for a in spans)
     finally:
         mgr.unregister_shuffle(h.shuffle_id)
         mgr.node.tracer.clear()

@@ -368,9 +368,21 @@ def _fuzz_rows(rng, n, cap, num_parts, width, groups, sum_words,
     return jnp.asarray(rows), jnp.asarray(p)
 
 
+# (n, cap, parts, width, groups) with the kernel's tiles past the live
+# rows (n << cap), which the kernel skips: live rows inside the first
+# tile, across many tiles, ending exactly on a tile edge, none at all,
+# and one segment crossing into the last live tile
+_DEAD_TILE_SHAPES = (
+    (39, 1024, 4, 6, 20),
+    (200, 2048, 4, 6, 50),
+    (128, 1024, 4, 6, 40),
+    (0, 1024, 4, 6, 3),
+    (200, 1024, 2, 6, 1),
+)
+
 # (n, cap, parts, width, groups): empty, sub-tile, non-tile-aligned n,
 # single row, single segment spanning every tile, all-valid full cap,
-# nearly-singleton groups (group-per-row stress)
+# nearly-singleton groups (group-per-row stress), then the dead tiles
 _FUZZ_SHAPES = (
     (0, 128, 4, 6, 3),
     (39, 128, 4, 6, 38),
@@ -380,7 +392,7 @@ _FUZZ_SHAPES = (
     (384, 384, 6, 6, 380),
     (300, 384, 4, 6, 38),
     (250, 256, 4, 7, 17),
-)
+) + _DEAD_TILE_SHAPES
 
 
 @pytest.mark.parametrize("shape", _FUZZ_SHAPES,
@@ -411,10 +423,48 @@ def test_blocked_segment_reduce_parity_fuzz(shape, sum_words,
     assert np.array_equal(np.asarray(jr)[:k], np.asarray(pr)[:k])
 
 
+@pytest.mark.parametrize("shape", _DEAD_TILE_SHAPES + ((300, 384, 4, 6, 38),),
+                         ids=lambda s: f"n{s[0]}_cap{s[1]}_g{s[4]}")
+@pytest.mark.parametrize("float_vals", (False, True),
+                         ids=("i32", "f32"))
+def test_blocked_segment_reduce_whole_buffer_matches_oracle(shape,
+                                                            float_vals):
+    """The whole returned buffer, not only its live prefix: the blocked
+    path returns the jnp oracle's rows byte for byte (the live rows,
+    then zero rows) and, past n_out, the sentinel partition, whatever
+    the kernel left in the output blocks of the tiles it skipped."""
+    _require_blocked_kernels()
+    n, cap, parts, width, groups = shape
+    rng = np.random.default_rng(n * 17 + cap)
+    rows, part = _fuzz_rows(rng, n, cap, parts, width, groups, 2,
+                            float_vals)
+    vdt = np.float32 if float_vals else np.int32
+    jr, jc, jn = S.segment_reduce_rows(rows, part, parts, width - 2, vdt,
+                                       sum_words=2, impl="jnp")
+    pr, pc, pn = S.segment_reduce_rows(rows, part, parts, width - 2, vdt,
+                                       sum_words=2, impl="pallas")
+    k = int(np.asarray(jn)[0])
+    assert k == int(np.asarray(pn)[0])
+    assert np.array_equal(np.asarray(jc), np.asarray(pc))
+    assert np.array_equal(np.asarray(jr), np.asarray(pr))
+    assert not np.asarray(pr)[k:].any()
+    rows_out, part2, n_out = S._segreduce_pallas(
+        rows, part, parts, 2, float_acc=float_vals,
+        interpret=S._resolve_interpret(None))
+    _, part_m = S._mask_past_n(rows_out, part2, n_out.reshape(-1)[0],
+                               parts)
+    want = np.full(cap, parts, np.int32)
+    want[:k] = np.repeat(np.arange(parts, dtype=np.int32), np.asarray(jc))
+    assert np.array_equal(np.asarray(part_m), want)
+
+
 @pytest.mark.parametrize("shape", ((0, 128, 4, 4), (39, 128, 4, 4),
                                    (129, 256, 4, 4), (300, 384, 3, 4),
-                                   (250, 256, 2, 8)),
-                         ids=lambda s: f"n{s[0]}_vw{s[3]}")
+                                   (250, 256, 2, 8), (39, 1024, 4, 4),
+                                   (128, 1024, 4, 4), (0, 1024, 4, 4),
+                                   (200, 2048, 3, 4)),
+                         ids=lambda s: f"n{s[0]}_vw{s[3]}"
+                         + (f"_cap{s[1]}" if s[1] > 384 else ""))
 def test_blocked_fused_wire_reduce_parity_fuzz(shape):
     """int8-dequant-fused segment-reduce vs the jnp unpack-then-reduce
     oracle: keys/partitions/n_out bit-exact, dequantized f32 sums
@@ -448,16 +498,20 @@ def test_blocked_fused_wire_reduce_parity_fuzz(shape):
                        rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("split", (0, 1, 29, 64))
-def test_blocked_merge_reduce_parity_fuzz(split):
+@pytest.mark.parametrize("split,cap", [
+    pytest.param(sp, cap, id=str(sp) if cap == 64 else f"{sp}_cap{cap}")
+    for sp, cap in ((0, 64), (1, 64), (29, 64), (64, 64), (0, 512),
+                    (29, 512), (64, 1024))])
+def test_blocked_merge_reduce_parity_fuzz(split, cap):
     """Blocked merge-path merge+reduce vs the jnp oracle on two sorted
-    runs of every skew (one side empty, singleton, balanced)."""
+    runs of every skew (one side empty, singleton, balanced), 64 rows
+    in all; in runs of ``cap`` rows each the merged buffer ends in
+    sentinel tiles the reduce skips."""
     _require_blocked_kernels()
-    import jax.numpy as jnp
     rng = np.random.default_rng(split + 5)
-    a_rows, a_p = _fuzz_rows(rng, split, max(split, 64), 4, W, 
+    a_rows, a_p = _fuzz_rows(rng, split, max(split, cap), 4, W,
                              max(1, split // 2), 2, False)
-    b_rows, b_p = _fuzz_rows(rng, 64 - split, 64, 4, W,
+    b_rows, b_p = _fuzz_rows(rng, 64 - split, cap, 4, W,
                              max(1, (64 - split) // 2), 2, False)
     outs = {}
     for impl in ("jnp", "pallas"):
