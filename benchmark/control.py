@@ -4,7 +4,9 @@
 
 The control is the plain reference one precision step down, put in the
 program's place (``yardstick.control``): ordered reads sorted on a 32-bit
-key, combined reads summed in int16. For each seed it makes the cell's
+key, combined reads summed in int16, plain reads carrying each record's
+words in 16 bits (the key's high word dropped, its low word and every
+value lane cut to 16 bits). For each seed it makes the cell's
 map outputs, compares the control's partitions with the reference as a
 run compares the program's (row counts of all partitions, rows of the
 sampled eighth), and over every partition, and prints one JSON line of

@@ -3,6 +3,7 @@
 The pinned values fix what every later run of the benchmark generates: a
 change to the package cannot move them, and a change here shows."""
 
+import collections
 import hashlib
 import json
 import os
@@ -146,6 +147,83 @@ def test_compare_catches_each_fault():
     missing = dict(sizes)
     del missing[0]
     assert y.compare(e, missing, {}, cfg)["partitions_missing"] == 1
+
+
+def _plain(name):
+    """``name``'s records under a hash partitioner and a plain read."""
+    return dict(_config(name), partitioner={"kind": "hash"}, read={})
+
+
+@pytest.mark.parametrize("name", ["hibench-terasort", "wordcount-zipf"])
+def test_plain_reference_is_each_partitions_multiset(name):
+    cfg = _plain(name)
+    inp = y.make_inputs(cfg, 1, SEED, rows_per_chip=3000)
+    want = {}
+    for keys, vals in inp.maps:
+        for r, k, v in zip(y.partition_of(keys, cfg, inp.num_partitions,
+                                          None), keys, vals):
+            want.setdefault(int(r), collections.Counter())[
+                (int(k), *v.tolist())] += 1
+    e = y.reference(inp, cfg)
+    assert e.sizes.sum() == inp.rows
+    for r, (k, v) in y.split_rows(e).items():
+        got = collections.Counter((int(a), *b.tolist())
+                                  for a, b in zip(k, v))
+        assert got == want.get(r, collections.Counter()), r
+
+
+def test_plain_order_is_a_lexsort_of_the_whole_row():
+    """Zipf keys repeat, splitmix lanes differ: the rows whose key repeats
+    are ordered by their lanes, as one lexsort over every column puts
+    them."""
+    cfg = dict(_plain("wordcount-zipf"),
+               value={"kind": "splitmix64", "lanes": 3})
+    inp = y.make_inputs(cfg, 1, SEED, rows_per_chip=20_000)
+    keys = np.concatenate([k for k, _ in inp.maps])
+    rows = y.as_rows(keys, np.concatenate([v for _, v in inp.maps]))
+    part = y.partition_of(keys, cfg, inp.num_partitions, None)
+    full = np.lexsort((*rows[:, :1:-1].T, keys, part))
+    assert np.array_equal(y.plain_order(keys, rows, part), full)
+    assert np.array_equal(y.plain_order(keys, rows),
+                          np.lexsort((*rows[:, :1:-1].T, keys)))
+
+
+def test_plain_reference_pinned():
+    cfg = _plain("hibench-terasort")
+    e = y.reference(y.make_inputs(cfg, 1, SEED, rows_per_chip=5000), cfg)
+    assert _digest(e.sizes) == "20951b7cea3b4f9e"
+    assert _digest(e.rows) == "8c0ab5885653ba29"
+
+
+def test_plain_compare_is_order_free():
+    """A plain read's partitions in any order compare equal; the same
+    rows out of key order fail an ordered read."""
+    cfg = _plain("hibench-terasort")
+    inp = y.make_inputs(cfg, 1, 14, rows_per_chip=5000)
+    e = y.reference(inp, cfg)
+    rng = np.random.default_rng(14)
+    shuffled = {}
+    for r, (k, v) in y.split_rows(e).items():
+        p = rng.permutation(k.shape[0])
+        shuffled[r] = (k[p], v[p])
+    sizes = _sizes(shuffled)
+    assert y.compare(e, sizes, shuffled, cfg) == {
+        "rows_wrong": 0, "partition_sizes_wrong": 0, "partitions_missing": 0}
+    ordered = dict(cfg, read={"ordered": True})
+    assert y.compare(e, sizes, shuffled, ordered)["rows_wrong"] > 0
+
+
+@pytest.mark.parametrize("name", ["hibench-terasort", "wordcount-zipf"])
+@pytest.mark.parametrize("seed", [21, 2**31 + 22, 2**33 + 23])
+def test_plain_control_fails(name, seed):
+    """The plain read's control carries each word in 16 bits: 62-bit keys
+    lose their high word, and Zipf words of 2^16 and above come back
+    wrong."""
+    cfg = _plain(name)
+    inp = y.make_inputs(cfg, 1, seed, rows_per_chip=100_000)
+    parts = y.split_rows(y.control(inp, cfg))
+    got = y.compare(y.reference(inp, cfg), _sizes(parts), parts, cfg)
+    assert got["rows_wrong"] > 0, got
 
 
 def test_sample_is_an_eighth_drawn_from_the_seed():

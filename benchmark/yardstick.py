@@ -3,14 +3,19 @@ comparison that decides ``correct``, and the byte counts of the work.
 
 Everything here is numpy and imports nothing of ``sparkucx_tpu``: a
 change to the package cannot move what the benchmark generates or how it
-judges the result. The generators are copies of the ones the chip smoke
-and the TeraSort workload use (splitmix64 key and payload streams, the
-Zipf word stream); ``tests/test_yardstick.py`` pins their output.
+judges the result. The generators (splitmix64 key and payload streams,
+the Zipf word stream) live here alone: the chip smoke
+(``chip_smoke.py``) imports them, and ``tests/test_yardstick.py`` pins
+their output.
 
 A configuration file (``configs/<name>.json``) describes the records
 (key distribution, value lanes), the partitioner and the read mode; the
 functions below take that dict, so a new deployment of the same record
-family is data only.
+family is data only. Three read modes are known: plain (``"read": {}``,
+every record delivered once to its partition, in no promised order),
+ordered (``{"ordered": true}``, each partition sorted by key) and
+combine sum (``{"combine": "sum"}``, one row a key with its lanes'
+sums).
 """
 
 from __future__ import annotations
@@ -184,6 +189,30 @@ def _canonical_ties(keys: np.ndarray, rows: np.ndarray) -> np.ndarray:
     return rows
 
 
+def plain_order(keys: np.ndarray, rows: np.ndarray,
+                part: Optional[np.ndarray] = None) -> np.ndarray:
+    """Indices that put ``rows`` (``as_rows``) in the plain read's one
+    canonical order: ``np.lexsort`` over the whole row, by partition
+    first where ``part`` is given, then by the key (its two words as one
+    int64), then by the value lanes, the first lane first.
+
+    A partition is a function of the key, so only rows whose key repeats
+    need the lanes: the whole row is sorted on for those rows alone, in
+    one call, and written back over the places their keys hold."""
+    primary = (keys,) if part is None else (keys, part)
+    order = np.lexsort(primary)
+    k = keys[order]
+    tie = np.zeros(k.shape[0], dtype=bool)
+    same = k[1:] == k[:-1]
+    tie[1:] |= same
+    tie[:-1] |= same
+    if tie.any():
+        sub = order[tie]
+        order[tie] = sub[np.lexsort((*rows[sub, :1:-1].T,
+                                     *(c[sub] for c in primary)))]
+    return order
+
+
 @dataclass
 class Expected:
     """What a correct read of the inputs delivers: per partition row
@@ -219,6 +248,7 @@ def reference(inputs: MapOutputs, config: Dict,
               sum_dtype=np.int64, key_words: int = 2) -> Expected:
     """The plain reference of the configured read over ``inputs``.
 
+    plain: each partition's rows, every record once, in ``plain_order``.
     ordered: each partition's rows sorted by key. combine sum: one row per
     distinct key of the partition, key-sorted, its lanes the sums
     (accumulated in ``sum_dtype``, stored in int32). ``key_words`` = 1
@@ -240,6 +270,12 @@ def reference(inputs: MapOutputs, config: Dict,
         order = np.lexsort((ukeys, upart))
         sizes = np.bincount(upart, minlength=inputs.num_partitions)
         return Expected(sizes, as_rows(ukeys[order], sums[order]))
+    if not read.get("ordered") and not read.get("combine"):
+        part = partition_of(keys, config, inputs.num_partitions,
+                            inputs.bounds)
+        rows = as_rows(keys, vals)
+        return Expected(np.bincount(part, minlength=inputs.num_partitions),
+                        rows[plain_order(keys, rows, part)])
     if not read.get("ordered"):
         raise ValueError(f"read mode {read!r} has no reference")
     part = partition_of(keys, config, inputs.num_partitions, inputs.bounds)
@@ -256,11 +292,21 @@ def reference(inputs: MapOutputs, config: Dict,
 def control(inputs: MapOutputs, config: Dict) -> Expected:
     """The reference one precision step down, in the program's place:
     ordered reads sort on a 32-bit key (the high word), combined reads
-    accumulate in int16. Each breaks a guarantee the configuration states
-    (global key order; exact per-key sums)."""
-    if config["read"].get("combine") == "sum":
+    accumulate in int16, plain reads carry each record's words in 16 bits
+    (the key's high word dropped, its low word and every value lane cut
+    to 16 bits). Each breaks a guarantee the configuration states (global
+    key order; exact per-key sums; every record delivered intact)."""
+    read = config["read"]
+    if read.get("combine") == "sum":
         return reference(inputs, config, sum_dtype=np.int16)
-    return reference(inputs, config, key_words=1)
+    if read.get("ordered"):
+        return reference(inputs, config, key_words=1)
+    expected = reference(inputs, config)
+    rows = expected.rows.copy()
+    rows[:, 0] &= 0xFFFF
+    rows[:, 1] = 0
+    rows[:, 2:] = rows[:, 2:].astype(np.int16)
+    return Expected(expected.sizes, rows)
 
 
 # -- the comparison ----------------------------------------------------------
@@ -289,13 +335,16 @@ def compare(expected: Expected, sizes: Dict[int, int],
     partition_sizes_wrong those whose row count differs from the
     reference's; rows_wrong the held rows that differ from the reference's
     row at the same place in the partition, plus rows missing or extra
-    there."""
+    there. A plain read promises no order within a partition: its rows
+    are put in ``plain_order`` first, so they match where the partition
+    holds the same records."""
     R = expected.sizes.shape[0]
     missing = sum(1 for r in range(R) if r not in sizes)
     sizes_wrong = sum(1 for r in range(R)
                       if sizes.get(r, 0) != expected.sizes[r])
     offsets = np.concatenate([[0], np.cumsum(expected.sizes)])
     ordered = bool(config["read"].get("ordered"))
+    plain = not ordered and not config["read"].get("combine")
     width = expected.rows.shape[1]
     wrong = 0
     for r, (keys, values) in parts.items():
@@ -307,6 +356,8 @@ def compare(expected: Expected, sizes: Dict[int, int],
             continue
         if ordered:
             got = _canonical_ties(keys, got)
+        elif plain:
+            got = got[plain_order(keys, got)]
         n = min(got.shape[0], want.shape[0])
         wrong += int(np.any(got[:n] != want[:n], axis=1).sum())
         wrong += abs(got.shape[0] - want.shape[0])
